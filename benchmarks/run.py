@@ -48,6 +48,9 @@ def main():
     )
     args = ap.parse_args()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     t0 = time.time()
     summary = {}
     known = {

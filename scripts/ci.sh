@@ -3,7 +3,7 @@
 # (see ROADMAP.md "Tier-1 verify").
 #
 #   scripts/ci.sh                     # full tier-1 suite (~10 min, 2 cores)
-#   scripts/ci.sh --kernels           # Pallas interpret-mode kernel lane
+#   scripts/ci.sh --kernels           # Pallas kernels: interpret parity + v5e compiles
 #   scripts/ci.sh --bench-smoke       # headless benchmarks/run.py --quick
 #   scripts/ci.sh --serve             # serving-runtime suite + bench smoke
 #   scripts/ci.sh --wire              # wire ingest-frontier suite
@@ -24,10 +24,12 @@ export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
 if [[ "${1:-}" == "--kernels" ]]; then
   # Focused kernel lane: every Pallas kernel against its oracle in
   # interpret mode, plus the fused-TSRC and sparse-TRD parity suites
-  # (v1 entry-side + v2 patch-side/fused∘sparse/adaptive-K).
+  # (v1 entry-side + v2 patch-side/fused∘sparse/adaptive-K), and the
+  # reproject-match kernels + EPIC step compiled for a described v5e.
   shift
   exec python -m pytest -q tests/test_kernels.py tests/test_fused_tsrc.py \
-    tests/test_sparse_tsrc.py tests/test_sparse_v2.py "$@"
+    tests/test_sparse_tsrc.py tests/test_sparse_v2.py \
+    tests/test_tpu_compile.py "$@"
 fi
 
 if [[ "${1:-}" == "--serve" ]]; then

@@ -25,7 +25,6 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.api.types import SensorChunk
@@ -101,12 +100,12 @@ class StreamPool:
             # Every leaf of (states, chunks) carries the stream axis in
             # front, so one prefix spec shards the whole step; each
             # device runs the vmapped step on its own shard.
-            step = shard_map(
+            step = jax.shard_map(
                 vstep,
                 mesh=mesh,
                 in_specs=(spec, spec),
                 out_specs=(spec, spec),
-                check_rep=False,
+                check_vma=False,
             )
             self._sharding = NamedSharding(mesh, spec)
         else:

@@ -33,6 +33,18 @@ import jax.numpy as jnp
 Array = jax.Array
 
 _EPS = 1e-6
+# The pose chain is a handful of 4x4 / 3x3 products: full f32 precision
+# costs nothing, while the TPU's default matmul precision (one bf16 pass)
+# would move warped pixels by whole pixels at sensor resolution.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _matmul(a: Array, b: Array) -> Array:
+    return jnp.matmul(a, b, precision=_HIGHEST)
+
+
+def _matvec(m: Array, v: Array) -> Array:
+    return jnp.einsum("...ij,...j->...i", m, v, precision=_HIGHEST)
 
 
 class Intrinsics(NamedTuple):
@@ -111,7 +123,7 @@ def rotation_xyz(angles: Array) -> Array:
         ],
         -2,
     )
-    return rz @ ry @ rx
+    return _matmul(_matmul(rz, ry), rx)
 
 
 def invert_pose(pose: Array) -> Array:
@@ -119,7 +131,7 @@ def invert_pose(pose: Array) -> Array:
     rot = pose[..., :3, :3]
     trans = pose[..., :3, 3]
     rot_t = jnp.swapaxes(rot, -1, -2)
-    new_t = -jnp.einsum("...ij,...j->...i", rot_t, trans)
+    new_t = -_matvec(rot_t, trans)
     return pose_from_rt(rot_t, new_t)
 
 
@@ -129,7 +141,7 @@ def relative_transform(src_pose: Array, dst_pose: Array) -> Array:
     Both poses are camera-to-world; the relative transform is
     ``inv(T_wc_dst) @ T_wc_src``.
     """
-    return invert_pose(dst_pose) @ src_pose
+    return _matmul(invert_pose(dst_pose), src_pose)
 
 
 def lift(uv: Array, depth: Array, intr: Intrinsics) -> Array:
@@ -166,9 +178,7 @@ def project(xyz: Array, intr: Intrinsics) -> Tuple[Array, Array, Array]:
 
 def transform_points(t4: Array, xyz: Array) -> Array:
     """Apply a 4x4 rigid transform to (..., 3) points."""
-    return (
-        jnp.einsum("...ij,...j->...i", t4[..., :3, :3], xyz) + t4[..., :3, 3]
-    )
+    return _matvec(t4[..., :3, :3], xyz) + t4[..., :3, 3]
 
 
 def reproject_points(
@@ -256,8 +266,8 @@ def eq1_reproject(
         ],
         -1,
     )
-    chain = _t_wc(intr) @ t_rel @ _t_cw(intr, depth)  # (..., 4, 4)
-    out = jnp.einsum("...ij,...j->...i", chain, homog)
+    chain = _matmul(_matmul(_t_wc(intr), t_rel), _t_cw(intr, depth))
+    out = _matvec(chain, homog)
     w = out[..., 3]
     valid = w > _EPS
     safe_w = jnp.where(valid, w, 1.0)

@@ -198,19 +198,20 @@ def build_epic_graph(
         _GRAPH_CACHE.move_to_end(key)
         return hit[1]
     graph = _build_epic_graph(cfg, models)
-    if _trace_state_clean():
+    if _no_active_trace():
         _GRAPH_CACHE[key] = (models, graph)
         while len(_GRAPH_CACHE) > _GRAPH_CACHE_MAX:
             _GRAPH_CACHE.popitem(last=False)
     return graph
 
 
-def _trace_state_clean() -> bool:
-    """True when no jax trace is active (safe to cache staged constants)."""
-    try:
-        return bool(jax.core.trace_state_clean())
-    except AttributeError:  # future-proof: changed private API -> no cache
-        return False
+def _no_active_trace() -> bool:
+    """True when no jax trace would stage the graph's array constants.
+
+    Under an active jit trace every primitive bind is staged out, so a
+    freshly built array is a ``Tracer``; outside one it is concrete.
+    """
+    return not isinstance(jnp.zeros(()), jax.core.Tracer)
 
 
 def _build_epic_graph(cfg: EPICConfig, models: EPICModels) -> StageGraph:

@@ -171,7 +171,7 @@ def tsrc_step(
     # One analytic pose inversion, then a broadcast batch-multiply —
     # inv(U_t) is entry-independent, so inverting it N times under vmap
     # (the old formulation) was pure waste.
-    t_rel = geo.invert_pose(pose) @ buf.pose
+    t_rel = geo.relative_transform(buf.pose, pose)
     backend_fn = get_backend(cfg.backend)
     fused_match = getattr(backend_fn, "fused_match", None)
     n_patches = origins.shape[0]
@@ -382,7 +382,7 @@ def tsrc_step_sequential_oracle(
 
     patch = buf.patch_size
     patches, origins = extract_patches(frame, patch)
-    t_rel = geo.invert_pose(pose) @ buf.pose  # invert once, batch-multiply
+    t_rel = geo.relative_transform(buf.pose, pose)  # invert once
     diff, coverage, bbox = reproject_match(
         buf.rgb, buf.depth, buf.origin, t_rel, frame, intr,
         window=cfg.window, backend="ref",

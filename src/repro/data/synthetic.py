@@ -49,6 +49,11 @@ _PALETTE = jnp.array(
 )
 
 PLANE_Y = 1.2  # ground plane height (+y is down)
+# Pixels rendered in one batch.  The ray caster holds a few f32 values
+# per pixel and object, so a sensor-size stream rendered in one vmap
+# needs more memory than an accelerator has (30 frames at 1024x1024 asked
+# a 16 GB TPU for 15.5 GB); longer or larger streams render in batches.
+_RENDER_BATCH_PX = 1 << 22
 SKY_DEPTH = 25.0
 
 
@@ -239,9 +244,14 @@ def generate_stream(key: Array, cfg: StreamConfig) -> Tuple[Stream, Scene]:
         return rgb, depth, obj, uv
 
     gaze_keys = jax.random.split(k_gaze, cfg.n_frames)
-    frames, depth, obj_id, gazes = jax.vmap(render_and_gaze)(
-        poses, target_pts, gaze_keys
-    )
+    xs = (poses, target_pts, gaze_keys)
+    per_batch = max(1, _RENDER_BATCH_PX // (cfg.hw[0] * cfg.hw[1]))
+    if per_batch >= cfg.n_frames:
+        frames, depth, obj_id, gazes = jax.vmap(render_and_gaze)(*xs)
+    else:
+        frames, depth, obj_id, gazes = jax.lax.map(
+            lambda x: render_and_gaze(*x), xs, batch_size=per_batch
+        )
     return (
         Stream(frames, depth, obj_id, poses, gazes, gaze_target, seg_of_frame),
         scene,

@@ -48,21 +48,28 @@ from jax.experimental import pallas as pl
 
 from repro.api.registry import register_backend
 from repro.core import geometry as geo
-from repro.kernels.reproject_match.kernel import _entry_scores
+from repro.kernels.reproject_match.kernel import (
+    ROWS,
+    _entry_scores,
+    _put_row,
+    _score_row,
+    _scores,
+    fold_vmap,
+    kernel_operands,
+    launch,
+    on_platform,
+    pad_entries,
+)
 
 Array = jax.Array
 
 
 def _fused_tsrc_kernel(
-    intr_ref,  # (3,) [f, cx, cy]
-    rgb_ref,  # (1, P, P, 3)
-    depth_ref,  # (1, P, P)
-    origin_ref,  # (1, 2)
-    trel_ref,  # (1, 4, 4)
-    frame_ref,  # (H, W, 3) full block
-    out_ref,  # (1, 8) packed [diff, coverage, bbox(4), pad(2)]
-    ovok_ref,  # (1, M) float 0/1 — bbox overlap >= o_min per patch
-    match_ref,  # (1, M) float 0/1 — overlap AND diff/coverage thresholds
+    intr_ref, origin_ref, trel_ref, rgb_ref, depth_ref, frame_ref,
+    out_ref,  # (8, 8) packed [diff, coverage, bbox(4), pad(2)] rows
+    ovok_ref,  # (8, M) float 0/1 — bbox overlap >= o_min per patch
+    match_ref,  # (8, M) float 0/1 — overlap AND diff/coverage thresholds
+    band_ref, sem,
     *,
     patch: int,
     window: int,
@@ -72,34 +79,23 @@ def _fused_tsrc_kernel(
     o_min: float,
     c_min: float,
 ):
-    diff, coverage, vmin, umin, vmax, umax = _entry_scores(
-        intr_ref,
-        rgb_ref,
-        depth_ref,
-        origin_ref,
-        trel_ref,
-        frame_ref,
-        patch=patch,
-        window=window,
-        frame_h=frame_h,
-        frame_w=frame_w,
+    r = pl.program_id(1) % ROWS
+    scores = _entry_scores(
+        intr_ref, origin_ref, trel_ref, rgb_ref, depth_ref, frame_ref,
+        band_ref, sem,
+        patch=patch, window=window, frame_h=frame_h, frame_w=frame_w,
     )
-    out_ref[0, 0] = diff
-    out_ref[0, 1] = coverage
-    out_ref[0, 2] = vmin
-    out_ref[0, 3] = umin
-    out_ref[0, 4] = vmax
-    out_ref[0, 5] = umax
-    out_ref[0, 6] = 0.0
-    out_ref[0, 7] = 0.0
+    diff, coverage, vmin, umin, vmax, umax = scores
+    _put_row(out_ref, r, _score_row(scores))
 
     # --- Spatial association against the implicit frame patch grid. --------
     gx = frame_w // patch
     gy = frame_h // patch
     m = gy * gx
-    jj = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
-    pv0 = ((jj // gx) * patch).astype(jnp.float32)
-    pu0 = ((jj % gx) * patch).astype(jnp.float32)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1).astype(jnp.float32)
+    gyi = jnp.floor(jj / gx)  # exact: small integers
+    pv0 = gyi * patch
+    pu0 = (jj - gyi * gx) * patch
     pv1 = pv0 + patch
     pu1 = pu0 + patch
     # Same formula as geo.bbox_overlap_fraction (kept in lockstep so the
@@ -110,8 +106,8 @@ def _fused_tsrc_kernel(
 
     ovok = overlap >= o_min
     entry_ok = (diff <= tau) & (coverage >= c_min)
-    ovok_ref[0, :] = ovok.astype(jnp.float32)[0]
-    match_ref[0, :] = (entry_ok & ovok).astype(jnp.float32)[0]
+    _put_row(ovok_ref, r, jnp.where(ovok, 1.0, 0.0))
+    _put_row(match_ref, r, jnp.where(entry_ok & ovok, 1.0, 0.0))
 
 
 @functools.partial(
@@ -130,7 +126,7 @@ def reproject_match_fused(
     tau: float = 0.08,
     o_min: float = 0.5,
     c_min: float = 0.6,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[Array, Array, Array, Array, Array]:
     """Fused TSRC match: one kernel pass per DC-buffer entry.
 
@@ -148,14 +144,7 @@ def reproject_match_fused(
     n, p = entry_rgb.shape[0], entry_rgb.shape[1]
     h, w = frame.shape[0], frame.shape[1]
     m = (h // p) * (w // p)
-    intr_vec = jnp.stack(
-        [
-            jnp.asarray(intr.f, jnp.float32),
-            jnp.asarray(intr.cx, jnp.float32),
-            jnp.asarray(intr.cy, jnp.float32),
-        ]
-    )
-
+    args = pad_entries(entry_rgb, entry_depth, entry_origin, t_rel, ROWS)
     kernel = functools.partial(
         _fused_tsrc_kernel,
         patch=p,
@@ -166,56 +155,47 @@ def reproject_match_fused(
         o_min=o_min,
         c_min=c_min,
     )
-    out, ovok, match = pl.pallas_call(
-        kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((3,), lambda i: (0,)),  # intrinsics: shared
-            pl.BlockSpec((1, p, p, 3), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, p, p), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 2), lambda i: (i, 0)),
-            pl.BlockSpec((1, 4, 4), lambda i: (i, 0, 0)),
-            pl.BlockSpec((h, w, 3), lambda i: (0, 0, 0)),  # frame: shared
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 8), lambda i: (i, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 8), jnp.float32),
-            jax.ShapeDtypeStruct((n, m), jnp.float32),
-            jax.ShapeDtypeStruct((n, m), jnp.float32),
-        ],
-        interpret=interpret,
-    )(intr_vec, entry_rgb, entry_depth, entry_origin, t_rel, frame)
 
-    diff = out[:, 0]
-    coverage = out[:, 1]
-    bbox = out[:, 2:6]
-    return diff, coverage, bbox, match > 0.5, ovok > 0.5
+    def batched(*operands):
+        return tuple(
+            launch(
+                kernel, operands, tile=1, out_cols=(8, m, m), window=window,
+                interpret=interpret,
+            )
+        )
+
+    out, ovok, match = fold_vmap(batched)(*kernel_operands(*args, frame, intr))
+    diff, coverage, bbox = _scores(out, n)
+    return diff, coverage, bbox, match[:n] > 0.5, ovok[:n] > 0.5
+
+
+def fused_match(
+    entry_rgb, entry_depth, entry_origin, t_rel, frame, intr,
+    *, window, tau, o_min, c_min,
+):
+    """:func:`reproject_match_fused`, compiled on TPU and interpreted
+    elsewhere (see :func:`~repro.kernels.reproject_match.kernel.
+    on_platform`)."""
+    return on_platform(
+        reproject_match_fused,
+        entry_rgb, entry_depth, entry_origin, t_rel, frame, intr,
+        window=window, tau=tau, o_min=o_min, c_min=c_min,
+    )
 
 
 @register_backend("fused")
 def _fused_backend(
-    entry_rgb, entry_depth, entry_origin, t_rel, frame, intr,
-    *, window, interpret,
+    entry_rgb, entry_depth, entry_origin, t_rel, frame, intr, *, window
 ):
     """Standard reproject-match contract (diff, coverage, bbox) served
     by the fused kernel — thresholds don't affect these outputs."""
-    diff, coverage, bbox, _, _ = reproject_match_fused(
-        entry_rgb,
-        entry_depth,
-        entry_origin,
-        t_rel,
-        frame,
-        intr,
-        window=window,
-        interpret=interpret,
+    diff, coverage, bbox, _, _ = fused_match(
+        entry_rgb, entry_depth, entry_origin, t_rel, frame, intr,
+        window=window, tau=0.08, o_min=0.5, c_min=0.6,
     )
     return diff, coverage, bbox
 
 
 # Capability attribute: tsrc_step detects this and runs the whole match
 # (thresholds + update mask) as one kernel — see core/tsrc.py.
-_fused_backend.fused_match = reproject_match_fused
+_fused_backend.fused_match = fused_match

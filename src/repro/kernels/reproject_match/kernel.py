@@ -5,59 +5,113 @@ Hardware mapping (paper Section 4.1 -> TPU):
 * The EPIC accelerator's *reprojection engine* walks DC-buffer entries,
   reprojects each bounding box, and only then runs the expensive pixel-level
   compare. On TPU the same structure becomes a grid over entries with each
-  grid step owning one entry's (P, P) tile in VMEM.
+  grid step owning one entry's P x P pixels in VMEM.
 * The ASIC's irregular gather (bilinear sampling of the current frame at
   warped coordinates) has no efficient TPU analogue — TPU vector memory has
-  no per-lane gather. We therefore *rewrite bilinear sampling as two dense
+  no per-lane gather. We therefore *rewrite bilinear sampling as dense
   matmuls* against one-hot interpolation operators built with
-  ``broadcasted_iota``: for warped pixel k and window row r,
+  ``broadcasted_iota``: for warped pixel k and window row r / column c,
 
-      A[k, r] = (r == floor(v_k)) (1 - dv_k) + (r == floor(v_k) + 1) dv_k
-      B[k, c] = (c == floor(u_k)) (1 - du_k) + (c == floor(u_k) + 1) du_k
+      A[r, k] = (r == floor(v_k)) (1 - dv_k) + (r == floor(v_k) + 1) dv_k
+      B[c, k] = (c == floor(u_k)) (1 - du_k) + (c == floor(u_k) + 1) du_k
 
-      sampled[k, :] = sum_c B[k, c] * (A @ win)[k, c, :]
+      sampled[ch, k] = sum_c B[c, k] * (win[ch]^T @ A)[c, k]
 
   This trades ~W x more MACs for perfectly regular MXU work — the canonical
   TPU bargain (dense masked compute replaces irregular skipping). The MACs
-  are tiny (K*W*(3W) ~ 3.1M for P=16, W=32) against the MXU's 197 TFLOP/s.
+  are tiny (3*K*W*W ~ 0.8M for P=16, W=32) against the MXU's 197 TFLOP/s.
 * The ASIC's bbox prefilter survives as the *window*: a ``window x window``
-  dynamic slice of the frame centred on the warped bbox is the only frame
-  data the entry's compare ever touches, bounding the VMEM working set.
+  slice of the frame centred on the warped bbox is the only frame data the
+  entry's compare ever touches. The frame therefore stays in HBM
+  (``memory_space=pl.ANY``); each entry DMAs the smallest tile-aligned
+  band holding its window (HBM slices start on the (8, 128) tile grid,
+  see :func:`band_shape`) and rotates the window to the band's corner —
+  the working set is independent of the frame size.
 
-VMEM budget per grid step (P=32, W=64, fp32):
-  entry tile  32*32*(3+1)*4            =  16 KiB
-  frame       held once, H*W*3*4       = 192 KiB at 128x128 (block-shared)
-  window      64*64*3*4                =  48 KiB
-  A/B         2 * K*W*4 = 2*1024*64*4  = 512 KiB   (dominant; fine vs 16 MiB)
+Layouts (lane-dense, so nothing pads a 3-wide minor dimension to 128):
 
-Outputs are packed as one (N, 8) row per entry:
-  [diff, coverage, vmin, umin, vmax, umax, 0, 0]
-so the kernel has a single 2D output block (TPU-friendly layout).
+* the ``K = P * P`` warped pixels of an entry run along lanes — every
+  per-pixel quantity is a ``(1, K)`` row, entry pixels are ``(3, K)``;
+* the frames are channel-planar ``(B, 3, H, W)`` in HBM: a vmapped call
+  (a pool of streams) becomes the grid's leading frame-batch axis
+  (:func:`fold_vmap`), since Pallas cannot vmap an HBM operand itself;
+* per-entry scalars (intrinsics, origins, the 4x4 transforms) sit in SMEM;
+* outputs are blocks of 8 rows, one ``[diff, coverage, vmin, umin, vmax,
+  umax, 0, 0]`` row per entry.
+
+VMEM working set per grid step (P=16, W=32, fp32): entry pixels 3*K*4 =
+3 KiB, band 3*40*256*4 = 120 KiB, interpolators 2*W*K*4 = 64 KiB, output
+block 256 B — far under the scoped-VMEM default at any frame size.
+
+Interpret vs compiled: the kernel-level functions take an explicit
+``interpret`` flag. :func:`on_platform` runs one compiled where the
+program is lowered for TPU and interpreted everywhere else (the CPU test
+path) — the choice follows the lowering platform, never a user option.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import geometry as geo
 
 Array = jax.Array
 
 _EPS = 1e-6
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+# Output rows per block: Mosaic tiles the second-minor dimension by 8.
+ROWS = 8
+
+
+def on_platform(kernel_fn, *args, **static):
+    """Call a Pallas entry point compiled on TPU, interpreted elsewhere.
+
+    ``jax.lax.platform_dependent`` resolves the branch when the program is
+    lowered, so a program lowered for TPU — on the chip or for a described
+    topology — carries the Mosaic kernel, and one lowered for CPU the
+    interpreter.
+    """
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=functools.partial(kernel_fn, **static, interpret=False),
+        default=functools.partial(kernel_fn, **static, interpret=True),
+    )
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def band_shape(window: int, frame_h: int, frame_w: int) -> Tuple[int, int]:
+    """Rows x columns of the frame band one entry DMAs from HBM.
+
+    HBM slices start on the (8, 128) tile grid and Mosaic rotates only
+    whole tiles, so the band is the window rounded out to that grid —
+    ``window + 7`` rows and ``window + 127`` columns, rounded up to whole
+    tiles — and at most the frame as padded by :func:`kernel_operands`.
+    """
+    return (
+        min(_round_up(window + 7, 8), _round_up(frame_h, 8)),
+        min(_round_up(window + 127, 128), _round_up(frame_w, 128)),
+    )
 
 
 def _entry_scores(
-    intr_ref,  # (3,) [f, cx, cy] camera intrinsics
-    rgb_ref,  # (E, P, P, 3) entry pixels I_c
-    depth_ref,  # (E, P, P) entry depth d_c
-    origin_ref,  # (E, 2) entry top-left (row, col)
-    trel_ref,  # (E, 4, 4) source->current transform
-    frame_ref,  # (H, W, 3) current frame F_t (full block)
+    intr_ref,  # SMEM (1, 3) [f, cx, cy]
+    origin_ref,  # SMEM (E, 1, 2) entry top-left (row, col)
+    trel_ref,  # SMEM (E, 1, 16) source->current transforms, row-major
+    rgb_ref,  # VMEM (E, 3, K) entry pixels I_c, channel-planar
+    depth_ref,  # VMEM (E, 1, K) entry depth d_c
+    frame_ref,  # HBM (B, 3, H, W) current frames F_t, channel-planar
+    band_ref,  # VMEM scratch (3, BH, BW), see band_shape
+    sem,  # DMA semaphore
     *,
     patch: int,
     window: int,
@@ -67,68 +121,75 @@ def _entry_scores(
 ):
     """Shared kernel body: warp one entry, sample, and score it.
 
-    Returns the per-entry scalars ``(diff, coverage, vmin, umin, vmax,
-    umax)``.  Factored out of :func:`_reproject_match_kernel` so the
-    fused TSRC kernel (``fused.py``) and the entry-tiled kernel below
-    run the *same ops in the same order* — their diff/coverage/bbox
-    outputs are bitwise identical to this kernel's.  ``e`` indexes the
-    entry within the grid step's block (0 for the one-entry-per-step
-    kernels; the tile row for :func:`reproject_match_pallas_tiled`).
+    Returns ``(1, 1)`` vectors ``(diff, coverage, vmin, umin, vmax,
+    umax)``.  Factored out so the one-entry-per-step kernel, the
+    entry-tiled kernel and the fused TSRC kernel (``fused.py``) run the
+    *same ops in the same order* — their outputs are bitwise identical.
+    ``e`` indexes the entry within the grid step's block; grid axis 0
+    indexes the frame batch.
     """
     p = patch
     k = p * p
-    intr_f = intr_ref[0]
-    intr_cx = intr_ref[1]
-    intr_cy = intr_ref[2]
+    f = intr_ref[0, 0]
+    cx = intr_ref[0, 1]
+    cy = intr_ref[0, 2]
+    oy = origin_ref[e, 0, 0]
+    ox = origin_ref[e, 0, 1]
+    t = [trel_ref[e, 0, j] for j in range(12)]
 
     # --- Warp the entry's pixel grid into the current view (Eq. 1). --------
-    depth = depth_ref[e]  # (P, P)
-    oy = origin_ref[e, 0]
-    ox = origin_ref[e, 1]
-    vv = jax.lax.broadcasted_iota(jnp.float32, (p, p), 0) + oy  # rows (v)
-    uu = jax.lax.broadcasted_iota(jnp.float32, (p, p), 1) + ox  # cols (u)
-
-    t = trel_ref[e]  # (4, 4)
-    x1 = (uu - intr_cx) / intr_f * depth
-    y1 = (vv - intr_cy) / intr_f * depth
+    kk = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1).astype(jnp.float32)
+    row = jnp.floor(kk / p)  # exact: small integers
+    vv = row + oy  # rows (v)
+    uu = (kk - row * p) + ox  # cols (u)
+    depth = depth_ref[e]  # (1, K)
+    x1 = (uu - cx) / f * depth
+    y1 = (vv - cy) / f * depth
     z1 = depth
-    x2 = t[0, 0] * x1 + t[0, 1] * y1 + t[0, 2] * z1 + t[0, 3]
-    y2 = t[1, 0] * x1 + t[1, 1] * y1 + t[1, 2] * z1 + t[1, 3]
-    z2 = t[2, 0] * x1 + t[2, 1] * y1 + t[2, 2] * z1 + t[2, 3]
+    x2 = t[0] * x1 + t[1] * y1 + t[2] * z1 + t[3]
+    y2 = t[4] * x1 + t[5] * y1 + t[6] * z1 + t[7]
+    z2 = t[8] * x1 + t[9] * y1 + t[10] * z1 + t[11]
     in_front = z2 > _EPS
     safe_z = jnp.where(in_front, z2, 1.0)
-    u2 = x2 / safe_z * intr_f + intr_cx  # (P, P) warped u
-    v2 = y2 / safe_z * intr_f + intr_cy  # (P, P) warped v
+    u2 = x2 / safe_z * f + cx  # (1, K) warped u
+    v2 = y2 / safe_z * f + cy  # (1, K) warped v
 
     # --- Corner bbox (the reprojection engine's prefilter). ----------------
-    cu = jnp.stack([u2[0, 0], u2[0, p - 1], u2[p - 1, 0], u2[p - 1, p - 1]])
-    cv = jnp.stack([v2[0, 0], v2[0, p - 1], v2[p - 1, 0], v2[p - 1, p - 1]])
-    cfrnt = jnp.stack(
-        [
-            in_front[0, 0],
-            in_front[0, p - 1],
-            in_front[p - 1, 0],
-            in_front[p - 1, p - 1],
-        ]
+    corner = (kk == 0) | (kk == p - 1) | (kk == k - p) | (kk == k - 1)
+    inf = jnp.float32(jnp.inf)
+    vmin = jnp.min(jnp.where(corner, v2, inf), axis=1, keepdims=True)
+    vmax = jnp.max(jnp.where(corner, v2, -inf), axis=1, keepdims=True)
+    umin = jnp.min(jnp.where(corner, u2, inf), axis=1, keepdims=True)
+    umax = jnp.max(jnp.where(corner, u2, -inf), axis=1, keepdims=True)
+    n_behind = jnp.sum(
+        jnp.where(corner & ~in_front, 1.0, 0.0), axis=1, keepdims=True
     )
-    vmin, vmax = jnp.min(cv), jnp.max(cv)
-    umin, umax = jnp.min(cu), jnp.max(cu)
-    bbox_valid = jnp.all(cfrnt)
+    bbox_valid = n_behind == 0.0
 
-    # --- Window slice of the frame centred on the bbox. --------------------
-    cy = 0.5 * (vmin + vmax)
-    cx = 0.5 * (umin + umax)
-    woy = jnp.clip(jnp.floor(cy - window / 2.0), 0.0, float(frame_h - window))
-    wox = jnp.clip(jnp.floor(cx - window / 2.0), 0.0, float(frame_w - window))
-    win = frame_ref[
-        pl.dslice(woy.astype(jnp.int32), window),
-        pl.dslice(wox.astype(jnp.int32), window),
-        :,
-    ]  # (W, W, 3)
+    # --- DMA the tile-aligned frame band holding the window. ---------------
+    wcy = 0.5 * (vmin + vmax)
+    wcx = 0.5 * (umin + umax)
+    woy = jnp.clip(jnp.floor(wcy - window / 2.0), 0.0, float(frame_h - window))
+    wox = jnp.clip(jnp.floor(wcx - window / 2.0), 0.0, float(frame_w - window))
+    bh, bw = band_ref.shape[1], band_ref.shape[2]
+    woy_i = woy.astype(jnp.int32)[0, 0]
+    wox_i = wox.astype(jnp.int32)[0, 0]
+    r0 = pl.multiple_of(
+        jnp.minimum((woy_i // 8) * 8, _round_up(frame_h, 8) - bh), 8
+    )
+    c0 = pl.multiple_of(
+        jnp.minimum((wox_i // 128) * 128, _round_up(frame_w, 128) - bw), 128
+    )
+    copy = pltpu.make_async_copy(
+        frame_ref.at[pl.program_id(0), :, pl.ds(r0, bh), pl.ds(c0, bw)],
+        band_ref,
+        sem,
+    )
+    copy.start()
 
-    # --- Bilinear sampling as two dense matmuls (see module docstring). ----
-    lu = (u2 - wox).reshape(k)  # window-local u per warped pixel
-    lv = (v2 - woy).reshape(k)
+    # --- Bilinear sampling as dense matmuls (see module docstring). --------
+    lu = u2 - wox  # window-local u per warped pixel
+    lv = v2 - woy
     u0 = jnp.floor(lu)
     v0 = jnp.floor(lv)
     du = lu - u0
@@ -139,65 +200,229 @@ def _entry_scores(
     u0c = jnp.clip(u0, 0.0, float(window - 2))
     v0c = jnp.clip(v0, 0.0, float(window - 2))
 
-    cols = jax.lax.broadcasted_iota(jnp.float32, (k, window), 1)
-    a = jnp.where(cols == v0c[:, None], (1.0 - dv)[:, None], 0.0) + jnp.where(
-        cols == v0c[:, None] + 1.0, dv[:, None], 0.0
-    )  # (K, W) row interpolator
-    b = jnp.where(cols == u0c[:, None], (1.0 - du)[:, None], 0.0) + jnp.where(
-        cols == u0c[:, None] + 1.0, du[:, None], 0.0
-    )  # (K, W) col interpolator
+    rows = jax.lax.broadcasted_iota(jnp.int32, (window, k), 0).astype(
+        jnp.float32
+    )
+    a = jnp.where(rows == v0c, 1.0 - dv, 0.0) + jnp.where(
+        rows == v0c + 1.0, dv, 0.0
+    )  # (W, K) row interpolator
+    b = jnp.where(rows == u0c, 1.0 - du, 0.0) + jnp.where(
+        rows == u0c + 1.0, du, 0.0
+    )  # (W, K) col interpolator
 
-    t1 = jnp.dot(
-        a, win.reshape(window, window * 3), preferred_element_type=jnp.float32
-    ).reshape(k, window, 3)
-    sampled = jnp.sum(b[:, :, None] * t1, axis=1)  # (K, 3)
+    # Rotate the window to the band's top-left corner (exact data
+    # movement), then slice it out statically.
+    shift_r = (bh - (woy_i - r0)) % bh
+    shift_c = (bw - (wox_i - c0)) % bw
+    copy.wait()
+    absdiff = None
+    for ch in range(3):
+        band = pltpu.roll(pltpu.roll(band_ref[ch], shift_r, 0), shift_c, 1)
+        win = band[:window, :window]  # (W rows, W cols)
+        t1 = jax.lax.dot_general(
+            win,
+            a,
+            (((0,), (0,)), ((), ())),
+            precision=_HIGHEST,
+            preferred_element_type=jnp.float32,
+        )  # (W cols, K): frame rows interpolated
+        sampled = jnp.sum(b * t1, axis=0, keepdims=True)  # (1, K)
+        term = jnp.abs(sampled - rgb_ref[e, pl.ds(ch, 1), :])
+        absdiff = term if absdiff is None else absdiff + term
+    absdiff = absdiff / 3.0
 
     # --- Masked mean |I_c - sampled| + coverage. ----------------------------
-    valid = (in_front.reshape(k) & in_win).astype(jnp.float32)
-    entry = rgb_ref[e].reshape(k, 3)
-    absdiff = jnp.mean(jnp.abs(sampled - entry), axis=-1)  # (K,)
-    nvalid = jnp.sum(valid)
+    valid = jnp.where(in_front & in_win, 1.0, 0.0)  # (1, K)
+    nvalid = jnp.sum(valid, axis=1, keepdims=True)
     denom = jnp.maximum(nvalid, 1.0)
-    diff = jnp.sum(absdiff * valid) / denom
+    diff = jnp.sum(absdiff * valid, axis=1, keepdims=True) / denom
     diff = jnp.where(nvalid > 0, diff, 1.0)
     coverage = jnp.where(bbox_valid, nvalid / float(k), 0.0)
     return diff, coverage, vmin, umin, vmax, umax
 
 
-def _reproject_match_kernel(
-    intr_ref,
-    rgb_ref,
-    depth_ref,
-    origin_ref,
-    trel_ref,
-    frame_ref,
-    out_ref,  # (1, 8) packed [diff, coverage, bbox(4), pad(2)]
+def _score_row(scores, width: int = 8) -> Array:
+    """Pack ``(1, 1)`` scores into one ``(1, width)`` output row."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    row = jnp.zeros((1, width), jnp.float32)
+    for j, s in enumerate(scores):
+        row = jnp.where(lane == j, s, row)
+    return row
+
+
+def _put_row(out_ref, r, row) -> None:
+    """Write ``row`` into row ``r`` of an 8-row output block."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+    out_ref[...] = jnp.where(rows == r, row, out_ref[...])
+
+
+def fold_vmap(batched_fn: Callable) -> Callable:
+    """Unbatched entry point over ``batched_fn``, whose operands and
+    results all carry a leading frame-batch axis.
+
+    Pallas' own vmap rule cannot batch an operand left in HBM
+    (``pl.ANY``), so a vmapped call — a pool of streams — instead folds
+    its axis into that batch axis: one kernel launch over a
+    ``(batch, entries)`` grid, nested vmaps included.
+    """
+
+    def broadcast(axis_size, in_batched, args):
+        return jax.tree.map(
+            lambda a, b: a if b else jnp.broadcast_to(a, (axis_size, *a.shape)),
+            tuple(args),
+            tuple(in_batched),
+        )
+
+    def all_batched(out):
+        return jax.tree.map(lambda _: True, out)
+
+    @jax.custom_batching.custom_vmap
+    def batched(*args):
+        return batched_fn(*args)
+
+    @batched.def_vmap
+    def _fold(axis_size, in_batched, *args):
+        args = broadcast(axis_size, in_batched, args)
+        out = batched(*jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), args))
+        out = jax.tree.map(lambda o: o.reshape(axis_size, -1, *o.shape[1:]), out)
+        return out, all_batched(out)
+
+    @jax.custom_batching.custom_vmap
+    def single(*args):
+        out = batched(*jax.tree.map(lambda a: a[None], args))
+        return jax.tree.map(lambda o: o[0], out)
+
+    @single.def_vmap
+    def _batch(axis_size, in_batched, *args):
+        out = batched(*broadcast(axis_size, in_batched, args))
+        return out, all_batched(out)
+
+    return single
+
+
+def pad_entries(entry_rgb, entry_depth, entry_origin, t_rel, multiple: int):
+    """Pad the entry axis to a multiple with benign entries (identity
+    transform, unit depth); callers slice the padding rows off."""
+    n, p = entry_rgb.shape[0], entry_rgb.shape[1]
+    pad = -n % multiple
+    if pad:
+        entry_rgb = jnp.concatenate(
+            [entry_rgb, jnp.zeros((pad, p, p, 3), entry_rgb.dtype)], 0
+        )
+        entry_depth = jnp.concatenate(
+            [entry_depth, jnp.ones((pad, p, p), entry_depth.dtype)], 0
+        )
+        entry_origin = jnp.concatenate(
+            [entry_origin, jnp.zeros((pad, 2), entry_origin.dtype)], 0
+        )
+        t_rel = jnp.concatenate(
+            [t_rel, jnp.broadcast_to(jnp.eye(4, dtype=t_rel.dtype), (pad, 4, 4))],
+            0,
+        )
+    return entry_rgb, entry_depth, entry_origin, t_rel
+
+
+def kernel_operands(entry_rgb, entry_depth, entry_origin, t_rel, frame, intr):
+    """Re-lay one frame's op inputs for the kernels (module docstring)."""
+    n, p = entry_rgb.shape[0], entry_rgb.shape[1]
+    h, w = frame.shape[0], frame.shape[1]
+    k = p * p
+    intr_vec = jnp.stack(
+        [
+            jnp.asarray(intr.f, jnp.float32),
+            jnp.asarray(intr.cx, jnp.float32),
+            jnp.asarray(intr.cy, jnp.float32),
+        ]
+    )
+    return (
+        intr_vec.reshape(1, 3),
+        entry_origin.astype(jnp.float32).reshape(n, 1, 2),
+        t_rel.astype(jnp.float32).reshape(n, 1, 16),
+        entry_rgb.reshape(n, k, 3).transpose(0, 2, 1),
+        entry_depth.reshape(n, 1, k),
+        jnp.pad(
+            frame.transpose(2, 0, 1),
+            ((0, 0), (0, -h % 8), (0, -w % 128)),
+        ),  # whole (8, 128) tiles, so every band slice is tile-aligned
+    )
+
+
+def launch(kernel, operands, *, tile: int, out_cols, window: int, interpret: bool):
+    """``pallas_call`` over a ``(batch, entries / tile)`` grid.
+
+    ``operands`` are :func:`kernel_operands` stacked over a leading frame
+    batch; ``tile`` entries are owned by each grid step.  Each output is
+    ``(batch, entries, cols)`` for ``cols`` in ``out_cols``, in blocks of
+    8 rows (``tile`` rows when tiled): a one-entry step writes its row of
+    the block it shares with its 7 neighbours.
+    """
+    bsz, n_pad, _, k = operands[3].shape
+    _, _, h, w = operands[5].shape
+    rows = ROWS if tile == 1 else tile
+    sq = pl.Squeezed()
+
+    def entry_block(*shape):
+        return pl.BlockSpec(
+            (sq, tile, *shape), lambda b, i: (b, i, *(0,) * len(shape))
+        )
+
+    def smem_entry_block(*shape):
+        return pl.BlockSpec(
+            (sq, tile, *shape),
+            lambda b, i: (b, i, *(0,) * len(shape)),
+            memory_space=pltpu.SMEM,
+        )
+
+    return pl.pallas_call(
+        kernel,
+        grid=(bsz, n_pad // tile),
+        in_specs=[
+            pl.BlockSpec((sq, 1, 3), lambda b, i: (b, 0, 0),
+                         memory_space=pltpu.SMEM),  # intrinsics
+            smem_entry_block(1, 2),  # origins
+            smem_entry_block(1, 16),  # transforms
+            entry_block(3, k),  # entry pixels
+            entry_block(1, k),  # entry depth
+            pl.BlockSpec(memory_space=pl.ANY),  # frames stay in HBM
+        ],
+        out_specs=[
+            pl.BlockSpec((sq, rows, c), lambda b, i: (b, i * tile // rows, 0))
+            for c in out_cols
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bsz, n_pad, c), jnp.float32) for c in out_cols
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((3, *band_shape(window, h, w)), jnp.float32),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+        interpret=interpret,
+    )(*operands)
+
+
+def _tiled_kernel(
+    intr_ref, origin_ref, trel_ref, rgb_ref, depth_ref, frame_ref,
+    out_ref,  # (rows, 8) packed output block, see launch
+    band_ref, sem,
     *,
     patch: int,
     window: int,
     frame_h: int,
     frame_w: int,
+    tile_n: int,
 ):
-    diff, coverage, vmin, umin, vmax, umax = _entry_scores(
-        intr_ref,
-        rgb_ref,
-        depth_ref,
-        origin_ref,
-        trel_ref,
-        frame_ref,
-        patch=patch,
-        window=window,
-        frame_h=frame_h,
-        frame_w=frame_w,
-    )
-    out_ref[0, 0] = diff
-    out_ref[0, 1] = coverage
-    out_ref[0, 2] = vmin
-    out_ref[0, 3] = umin
-    out_ref[0, 4] = vmax
-    out_ref[0, 5] = umax
-    out_ref[0, 6] = 0.0
-    out_ref[0, 7] = 0.0
+    first = pl.program_id(1) * tile_n
+    for j in range(tile_n):  # static unroll over the tile's entries
+        scores = _entry_scores(
+            intr_ref, origin_ref, trel_ref, rgb_ref, depth_ref, frame_ref,
+            band_ref, sem,
+            patch=patch, window=window, frame_h=frame_h, frame_w=frame_w, e=j,
+        )
+        _put_row(out_ref, (first + j) % out_ref.shape[0], _score_row(scores))
+
+
+def _scores(out, n):
+    return out[:n, 0], out[:n, 1], out[:n, 2:6]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -210,50 +435,30 @@ def reproject_match_pallas(
     intr: geo.Intrinsics,
     *,
     window: int = 64,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[Array, Array, Array]:
     """Pallas TPU implementation of the reproject-match op.
 
     Same contract as
     :func:`repro.kernels.reproject_match.ref.reproject_match_ref`.
+    One entry per grid step; the entry axis is padded to a multiple of
+    8 so each output block holds 8 entries' rows.
     """
     n, p = entry_rgb.shape[0], entry_rgb.shape[1]
     h, w = frame.shape[0], frame.shape[1]
-    intr_vec = jnp.stack(
-        [
-            jnp.asarray(intr.f, jnp.float32),
-            jnp.asarray(intr.cx, jnp.float32),
-            jnp.asarray(intr.cy, jnp.float32),
-        ]
-    )
-
+    args = pad_entries(entry_rgb, entry_depth, entry_origin, t_rel, ROWS)
     kernel = functools.partial(
-        _reproject_match_kernel,
-        patch=p,
-        window=window,
-        frame_h=h,
-        frame_w=w,
+        _tiled_kernel, patch=p, window=window, frame_h=h, frame_w=w, tile_n=1
     )
-    out = pl.pallas_call(
-        kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((3,), lambda i: (0,)),  # intrinsics: shared
-            pl.BlockSpec((1, p, p, 3), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, p, p), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 2), lambda i: (i, 0)),
-            pl.BlockSpec((1, 4, 4), lambda i: (i, 0, 0)),
-            pl.BlockSpec((h, w, 3), lambda i: (0, 0, 0)),  # frame: shared
-        ],
-        out_specs=pl.BlockSpec((1, 8), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 8), jnp.float32),
-        interpret=interpret,
-    )(intr_vec, entry_rgb, entry_depth, entry_origin, t_rel, frame)
 
-    diff = out[:, 0]
-    coverage = out[:, 1]
-    bbox = out[:, 2:6]
-    return diff, coverage, bbox
+    def batched(*operands):
+        return launch(
+            kernel, operands, tile=1, out_cols=(8,), window=window,
+            interpret=interpret,
+        )[0]
+
+    out = fold_vmap(batched)(*kernel_operands(*args, frame, intr))
+    return _scores(out, n)
 
 
 # ---------------------------------------------------------------------------
@@ -264,47 +469,8 @@ def reproject_match_pallas(
 # pays per-step dispatch/pipelining overhead that dominates at the small
 # candidate counts the sparse-TRD prefilter produces (K ~ 16-32); eight
 # entries per step amortises it while keeping the VMEM working set
-# (8 entry tiles + 8 windows + the shared frame) comfortably bounded.
+# (8 entry tiles + one band) comfortably bounded.
 TILE_N = 8
-
-
-def _reproject_match_tiled_kernel(
-    intr_ref,
-    rgb_ref,  # (TILE_N, P, P, 3)
-    depth_ref,  # (TILE_N, P, P)
-    origin_ref,  # (TILE_N, 2)
-    trel_ref,  # (TILE_N, 4, 4)
-    frame_ref,
-    out_ref,  # (TILE_N, 8) packed rows
-    *,
-    patch: int,
-    window: int,
-    frame_h: int,
-    frame_w: int,
-    tile_n: int,
-):
-    for j in range(tile_n):  # static unroll over the tile's entries
-        diff, coverage, vmin, umin, vmax, umax = _entry_scores(
-            intr_ref,
-            rgb_ref,
-            depth_ref,
-            origin_ref,
-            trel_ref,
-            frame_ref,
-            patch=patch,
-            window=window,
-            frame_h=frame_h,
-            frame_w=frame_w,
-            e=j,
-        )
-        out_ref[j, 0] = diff
-        out_ref[j, 1] = coverage
-        out_ref[j, 2] = vmin
-        out_ref[j, 3] = umin
-        out_ref[j, 4] = vmax
-        out_ref[j, 5] = umax
-        out_ref[j, 6] = 0.0
-        out_ref[j, 7] = 0.0
 
 
 @functools.partial(
@@ -320,7 +486,7 @@ def reproject_match_pallas_tiled(
     *,
     window: int = 64,
     tile_n: int = TILE_N,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[Array, Array, Array]:
     """Entry-tiled Pallas reproject-match: ``tile_n`` entries per grid step.
 
@@ -333,60 +499,17 @@ def reproject_match_pallas_tiled(
     n, p = entry_rgb.shape[0], entry_rgb.shape[1]
     h, w = frame.shape[0], frame.shape[1]
     tile = max(1, min(tile_n, n)) if n else 1
-    n_pad = -(-n // tile) * tile
-    pad = n_pad - n
-    if pad:
-        entry_rgb = jnp.concatenate(
-            [entry_rgb, jnp.zeros((pad, p, p, 3), entry_rgb.dtype)], 0
-        )
-        entry_depth = jnp.concatenate(
-            [entry_depth, jnp.ones((pad, p, p), entry_depth.dtype)], 0
-        )
-        entry_origin = jnp.concatenate(
-            [entry_origin, jnp.zeros((pad, 2), entry_origin.dtype)], 0
-        )
-        t_rel = jnp.concatenate(
-            [
-                t_rel,
-                jnp.broadcast_to(
-                    jnp.eye(4, dtype=t_rel.dtype), (pad, 4, 4)
-                ),
-            ],
-            0,
-        )
-    intr_vec = jnp.stack(
-        [
-            jnp.asarray(intr.f, jnp.float32),
-            jnp.asarray(intr.cx, jnp.float32),
-            jnp.asarray(intr.cy, jnp.float32),
-        ]
-    )
-
+    args = pad_entries(entry_rgb, entry_depth, entry_origin, t_rel, tile)
     kernel = functools.partial(
-        _reproject_match_tiled_kernel,
-        patch=p,
-        window=window,
-        frame_h=h,
-        frame_w=w,
+        _tiled_kernel, patch=p, window=window, frame_h=h, frame_w=w,
         tile_n=tile,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_pad // tile,),
-        in_specs=[
-            pl.BlockSpec((3,), lambda i: (0,)),  # intrinsics: shared
-            pl.BlockSpec((tile, p, p, 3), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((tile, p, p), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 4, 4), lambda i: (i, 0, 0)),
-            pl.BlockSpec((h, w, 3), lambda i: (0, 0, 0)),  # frame: shared
-        ],
-        out_specs=pl.BlockSpec((tile, 8), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 8), jnp.float32),
-        interpret=interpret,
-    )(intr_vec, entry_rgb, entry_depth, entry_origin, t_rel, frame)
 
-    diff = out[:n, 0]
-    coverage = out[:n, 1]
-    bbox = out[:n, 2:6]
-    return diff, coverage, bbox
+    def batched(*operands):
+        return launch(
+            kernel, operands, tile=tile, out_cols=(8,), window=window,
+            interpret=interpret,
+        )[0]
+
+    out = fold_vmap(batched)(*kernel_operands(*args, frame, intr))
+    return _scores(out, n)

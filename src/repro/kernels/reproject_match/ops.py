@@ -6,8 +6,8 @@ Backends are looked up by name in :mod:`repro.api.registry` (so
 ``backend="ref"`` — pure-jnp oracle (default; used by the streaming pipeline
 on CPU and inside SPMD lowering, where a TPU Pallas custom call cannot lower).
 
-``backend="pallas"`` — the Pallas TPU kernel (``kernel.py``), validated in
-interpret mode on CPU; on real TPU hardware this is the deployed hot path.
+``backend="pallas"`` — the Pallas TPU kernel (``kernel.py``): compiled
+where the program is lowered for TPU, interpreted on CPU (the test path).
 
 ``backend="pallas_tiled"`` — the entry-tiled Pallas kernel (``TILE_N``
 entries per grid step); same per-entry math as ``pallas``, but the grid-step
@@ -24,6 +24,11 @@ import jax
 
 from repro.api.registry import get_backend, register_backend
 from repro.core import geometry as geo
+from repro.kernels.reproject_match.kernel import (
+    on_platform,
+    reproject_match_pallas,
+    reproject_match_pallas_tiled,
+)
 from repro.kernels.reproject_match.ref import reproject_match_ref
 
 Array = jax.Array
@@ -31,10 +36,8 @@ Array = jax.Array
 
 @register_backend("ref")
 def _ref_backend(
-    entry_rgb, entry_depth, entry_origin, t_rel, frame, intr,
-    *, window, interpret,
+    entry_rgb, entry_depth, entry_origin, t_rel, frame, intr, *, window
 ):
-    del interpret  # ref path has no interpret mode
     return reproject_match_ref(
         entry_rgb, entry_depth, entry_origin, t_rel, frame, intr, window
     )
@@ -42,45 +45,27 @@ def _ref_backend(
 
 @register_backend("pallas")
 def _pallas_backend(
-    entry_rgb, entry_depth, entry_origin, t_rel, frame, intr,
-    *, window, interpret,
+    entry_rgb, entry_depth, entry_origin, t_rel, frame, intr, *, window
 ):
-    from repro.kernels.reproject_match.kernel import reproject_match_pallas
-
-    return reproject_match_pallas(
-        entry_rgb,
-        entry_depth,
-        entry_origin,
-        t_rel,
-        frame,
-        intr,
+    return on_platform(
+        reproject_match_pallas,
+        entry_rgb, entry_depth, entry_origin, t_rel, frame, intr,
         window=window,
-        interpret=interpret,
     )
 
 
 @register_backend("pallas_tiled")
 def _pallas_tiled_backend(
-    entry_rgb, entry_depth, entry_origin, t_rel, frame, intr,
-    *, window, interpret,
+    entry_rgb, entry_depth, entry_origin, t_rel, frame, intr, *, window
 ):
-    from repro.kernels.reproject_match.kernel import (
+    return on_platform(
         reproject_match_pallas_tiled,
-    )
-
-    return reproject_match_pallas_tiled(
-        entry_rgb,
-        entry_depth,
-        entry_origin,
-        t_rel,
-        frame,
-        intr,
+        entry_rgb, entry_depth, entry_origin, t_rel, frame, intr,
         window=window,
-        interpret=interpret,
     )
 
 
-@partial(jax.jit, static_argnames=("window", "backend", "interpret"))
+@partial(jax.jit, static_argnames=("window", "backend"))
 def reproject_match(
     entry_rgb: Array,
     entry_depth: Array,
@@ -91,7 +76,6 @@ def reproject_match(
     *,
     window: int = 64,
     backend: str = "ref",
-    interpret: bool = True,
 ) -> Tuple[Array, Array, Array]:
     """Warp buffered patches into the current view and score redundancy.
 
@@ -105,7 +89,6 @@ def reproject_match(
       window: sampling window side (op semantics; see ref.py).
       backend: registry name ("ref" | "pallas" | anything registered
         via repro.api.registry.register_backend).
-      interpret: run the Pallas kernel in interpret mode (CPU validation).
 
     Returns:
       diff (N,), coverage (N,), bbox (N, 4).
@@ -113,5 +96,5 @@ def reproject_match(
     fn = get_backend(backend)
     return fn(
         entry_rgb, entry_depth, entry_origin, t_rel, frame, intr,
-        window=window, interpret=interpret,
+        window=window,
     )

@@ -95,14 +95,14 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
             model, mesh, AdamWConfig(), shape_spec=shape,
             moment_dtype=mdt, accum=cfg.train_accum,
         )
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = step_fn.lower(
                 pshape, oshape, batch, jax.ShapeDtypeStruct((), jnp.int32)
             )
     elif shape.kind == "prefill":
         batch = model.batch_spec(shape)
         fn, _ = SV.jit_prefill(model, mesh, shape)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = fn.lower(pshape, batch)
     else:  # decode
         b = shape.global_batch
@@ -110,7 +110,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         sspecs = S.serve_specs(cfg, sshape, mesh, b)
         aux["cache_bytes_per_device"] = sharded_bytes(sshape, sspecs, mesh)
         fn, _ = SV.jit_decode_step(model, mesh, shape)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = fn.lower(
                 pshape,
                 sshape,

@@ -162,14 +162,9 @@ def remat_wrap(cfg, fn):
 
 
 def ambient_mesh_axes() -> Dict[str, int]:
-    """Axis sizes of the ambient (with mesh:) mesh; {} when none."""
-    try:
-        from jax.interpreters import pxla
-
-        m = pxla.thread_resources.env.physical_mesh
-        return {} if m.empty else dict(m.shape)
-    except Exception:  # noqa: BLE001 — future jax versions
-        return {}
+    """Axis sizes of the ambient (``jax.set_mesh``) mesh; {} when none."""
+    m = jax.sharding.get_abstract_mesh()
+    return {} if m.empty else dict(m.shape)
 
 
 def decode_seq_shard(batch: int, n_kv_heads: int, skv: int):

@@ -226,23 +226,6 @@ def _quant_all_to_all(x, ep_names, split_axis, concat_axis):
 # ---------------------------------------------------------------------------
 
 
-def _shard_map(region, mesh, in_specs, out_specs):
-    """shard_map across jax versions (jax.shard_map landed in 0.5;
-    0.4.x exposes it under jax.experimental with check_rep instead of
-    check_vma)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            region, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        region, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def moe_ffn_ep(p: Params, x: Array, cfg: ModelConfig):
     """EP MoE: local routing + all-to-all token exchange (DeepSeek-style).
 
@@ -254,16 +237,13 @@ def moe_ffn_ep(p: Params, x: Array, cfg: ModelConfig):
 
     Token layout inside the region: batch over the pure-DP axes, seq over
     the remaining EP axes, so every device owns a disjoint token slice.
-    Returns None when no suitable ambient mesh exists (single-host tests
-    fall back to the sort impl).
+    The mesh is the ambient one (``with jax.set_mesh(mesh):``).  Returns
+    None when no suitable ambient mesh exists (single-host tests fall
+    back to the sort impl).
     """
-    from jax.interpreters import pxla
     from jax.sharding import PartitionSpec as P
 
-    try:
-        mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:  # noqa: BLE001
-        return None
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return None
     ax = dict(mesh.shape)
@@ -364,7 +344,10 @@ def moe_ffn_ep(p: Params, x: Array, cfg: ModelConfig):
         P(),  # shared experts replicated
     )
     out_specs = (x_spec, P())
-    fn = _shard_map(region, mesh, in_specs, out_specs)
+    fn = jax.shard_map(
+        region, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
     shared = p.get("shared", {"_": jnp.zeros((), cdt)})
     out, aux = fn(
         x, p["router"], p["gate_w"], p["up_w"], p["down_w"], shared

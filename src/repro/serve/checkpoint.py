@@ -166,12 +166,15 @@ def snapshot_server(
     """Capture ``(device_tree, json_meta)`` of a live server.
 
     The device tree holds the per-tier :class:`~repro.serve.slots.
-    SlotStates` (immutable jax arrays — capturing the references IS a
-    consistent point-in-time snapshot) and every queued chunk; the meta
-    block holds everything host-side needed to re-bind it.  With
-    ``ingest`` given, its lock is held while capturing so a socket
-    thread cannot interleave a submit mid-snapshot, and the wire seq
-    cursors are included.
+    SlotStates` and every queued chunk; the meta block holds everything
+    host-side needed to re-bind it.  The slot states are *copied* on
+    device: off the CPU every pool step donates its state buffers, so a
+    captured reference would be invalidated by the very next tick while
+    an asynchronous save is still reading it.  The copies are
+    asynchronous device dispatches, so the tick path never waits for a
+    host transfer.  With ``ingest`` given, its lock is held while
+    capturing so a socket thread cannot interleave a submit
+    mid-snapshot, and the wire seq cursors are included.
     """
     if ingest is not None:
         if ingest.srv is not server:
@@ -272,7 +275,10 @@ def _snapshot_locked(
             "nacks": dict(ingest.nacks),
         }
 
-    tree = {"tiers": [p.states for p in pools], "queues": queues}
+    tree = {
+        "tiers": [jax.tree.map(jnp.copy, p.states) for p in pools],
+        "queues": queues,
+    }
     return tree, meta
 
 
@@ -409,7 +415,9 @@ def _restore_one(
     # sessions) and NOT through _host_bind.  Restored generation
     # counters therefore equal the checkpointed ones on both sides.
     for p, st in zip(pools, tree["tiers"]):
-        p.states = jax.device_put(st)
+        # Onto the pool's own placement: a stream-mesh pool's slot axis
+        # is sharded over its devices (``None`` = the default device).
+        p.states = jax.device_put(st, p._sharding)
         p.session_at = [None] * p.capacity
         p._slot_of = {}
     for p, gens in zip(pools, meta["host_generation"]):
@@ -516,8 +524,8 @@ class ServeCheckpointer:
     """Checkpoint-every-N-ticks with async writes and gc.
 
     Call :meth:`maybe_save` once per serving tick; every
-    ``every_ticks`` ticks it snapshots (cheap: reference capture +
-    host copy) and hands the write to an
+    ``every_ticks`` ticks it snapshots (cheap: one device-side copy of
+    the slot states) and hands the write to an
     :class:`~repro.checkpoint.store.AsyncSaver` so the tick path never
     blocks on disk.  A crash mid-save leaves the previous step intact
     (the store's tmp-dir + manifest-last protocol); :meth:`restore`

@@ -52,7 +52,6 @@ from typing import Any, Callable, Dict, Hashable, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.api.types import SensorChunk
@@ -316,12 +315,12 @@ class SlottedPool:
 
         if self.mesh is not None:
             spec = PartitionSpec(self.axis)
-            masked = shard_map(
+            masked = jax.shard_map(
                 masked,
                 mesh=self.mesh,
                 in_specs=(spec, spec, spec),
                 out_specs=(spec, spec),
-                check_rep=False,
+                check_vma=False,
             )
         return jax.jit(
             masked, donate_argnums=(0,) if self._donate else ()
@@ -370,12 +369,12 @@ class SlottedPool:
 
         if self.mesh is not None:
             spec = PartitionSpec(self.axis)
-            masked = shard_map(
+            masked = jax.shard_map(
                 masked,
                 mesh=self.mesh,
                 in_specs=(spec, spec, PartitionSpec(None, self.axis)),
                 out_specs=(spec, spec),
-                check_rep=False,
+                check_vma=False,
             )
         return jax.jit(
             masked, donate_argnums=(0,) if self._donate else ()

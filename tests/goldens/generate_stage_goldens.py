@@ -16,6 +16,14 @@ ran on, instead of the permuted post-insert occupancy) and the
 Refreshed again with Sparse TRD v2: every pre-existing leaf is unchanged
 bit for bit; only the ``n_patch_overflow`` / ``n_patch_checked`` counter
 leaves were appended (both 0 on the dense path pinned here).
+
+Regenerated for JAX 0.9, whose ``jax.random`` streams differ from the
+version the goldens were first made with: the synthetic input chunk (and
+the randomly initialised HIR weights) moved, not the pipeline.  The file
+now stores what the outputs were computed from — the input chunk under
+``input/{frames,poses,gazes,depth}`` and the HIR weights under
+``input/hir/<i>`` — and the tests read them from here, so a change of
+random-number generator can no longer move the goldens.
 """
 
 import os
@@ -51,7 +59,13 @@ def epic_cfg():
 def main():
     s = stream()
     chunk = api.SensorChunk(s.frames, s.poses, s.gazes, s.depth)
-    out = {}
+    hir_params = hir.init_params(jax.random.PRNGKey(7))
+    out = {
+        f"input/{name}": np.asarray(getattr(chunk, name))
+        for name in ("frames", "poses", "gazes", "depth")
+    }
+    for i, leaf in enumerate(jax.tree.leaves(hir_params)):
+        out[f"input/hir/{i}"] = np.asarray(leaf)
 
     def record(tag, state, stats):
         for i, leaf in enumerate(jax.tree.leaves(state)):
@@ -66,10 +80,7 @@ def main():
 
     # EPIC with a (randomly initialised) HIR saliency model — exercises
     # the saliency stage's learned path.
-    models = P.EPICModels(
-        depth_params=None,
-        hir_params=hir.init_params(jax.random.PRNGKey(7)),
-    )
+    models = P.EPICModels(depth_params=None, hir_params=hir_params)
     comp = api.get_compressor("epic")(epic_cfg(), models)
     state, stats = jax.jit(comp.step)(comp.init(), chunk)
     record("epic_hir", state, stats)

@@ -252,7 +252,8 @@ def test_ep_moe_matches_sort_subprocess():
         out = {}
         base = get_smoke_config("deepseek-v2-lite-16b").replace(
             moe_capacity_factor=8.0)
-        mesh = jax.make_mesh((2,4), ("data","model"))
+        mesh = jax.make_mesh((2,4), ("data","model"),
+                             axis_types=(jax.sharding.AxisType.Auto,)*2)
         B,S,D = 8, 16, base.d_model
         x = jax.random.normal(jax.random.fold_in(key,2), (B,S,D))*0.3
         for name, cfg in (
@@ -261,7 +262,7 @@ def test_ep_moe_matches_sort_subprocess():
         ):
             p = MOE.init_moe(jax.random.fold_in(key,1), cfg)
             y_ref, _ = MOE.moe_ffn_sort(p, x, cfg)
-            with mesh:
+            with jax.set_mesh(mesh):
                 y_ep, _ = jax.jit(lambda p,x: MOE.moe_ffn_ep(p,x,cfg))(p, x)
                 g1 = jax.jit(jax.grad(
                     lambda p,x: MOE.moe_ffn_ep(p,x,cfg)[0].sum()))(p,x)
@@ -299,8 +300,9 @@ def test_ep_moe_int8_dispatch_subprocess():
         x = jax.random.normal(jax.random.fold_in(key,2),
                               (8, 16, base.d_model))*0.3
         y_ref, _ = MOE.moe_ffn_sort(p, x, base)
-        mesh = jax.make_mesh((2,4), ("data","model"))
-        with mesh:
+        mesh = jax.make_mesh((2,4), ("data","model"),
+                             axis_types=(jax.sharding.AxisType.Auto,)*2)
+        with jax.set_mesh(mesh):
             yq, _ = jax.jit(lambda p,x: MOE.moe_ffn_ep(p,x,cfgq))(p, x)
             gq = jax.jit(jax.grad(
                 lambda p,x: MOE.moe_ffn_ep(p,x,cfgq)[0].sum()))(p,x)

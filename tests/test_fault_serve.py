@@ -141,6 +141,39 @@ class TestSnapshotRestore:
         # itself never traces a pool program
         assert all(v == 1 for v in srv2.step_cache_sizes().values())
 
+    @pytest.mark.parametrize("tiers", [None, (2, 2)], ids=["flat", "tiered"])
+    def test_snapshot_survives_donated_steps(self, tiers):
+        """With donation on (the accelerator default), the ticks after a
+        snapshot consume the live state buffers; the snapshot owns its
+        copy and still reads back the state it captured."""
+        chunks = _chunks(7)
+
+        def serve(donate, n_ticks):
+            srv = StreamServer(
+                _comp(8), _server_cfg(tiers=tiers), donate=donate
+            )
+            srv.admit(1)
+            srv.admit(2)
+            for i in range(n_ticks):
+                for sid in (1, 2):
+                    assert srv.submit(sid, chunks[i])
+                srv.tick()
+            return srv, srv.pool.tiers if tiers else [srv.pool]
+
+        # What the snapshot must hold, from a twin that never donates (a
+        # host read of a live CPU buffer would pin it against donation).
+        _, twin = serve(False, 2)
+        want = [p.states for p in twin]
+        srv, pools = serve(True, 2)
+        tree, _ = snapshot_server(srv)
+        live = jax.tree.leaves([p.states for p in pools])
+        for i in range(2, 4):
+            for sid in (1, 2):
+                assert srv.submit(sid, chunks[i])
+            srv.tick()
+        assert any(x.is_deleted() for x in live)  # the steps donated
+        _assert_tree_bitwise(tree["tiers"], want, "snapshot")
+
     def test_counters_and_evicted_survive(self, tmp_path):
         srv = StreamServer(_comp(0), _server_cfg(k_ladder=None))
         chunks = _chunks(5)

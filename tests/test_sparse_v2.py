@@ -247,6 +247,7 @@ class TestFusedSparseComposition:
         d_f, c_f, b_f, pair, ovok = reproject_match_fused(
             rgb, dep, orig, t_rel, frame, _intr(),
             window=window, tau=tau, o_min=o_min, c_min=c_min,
+            interpret=True,
         )
         d_p, c_p, b_p = reproject_match(
             rgb, dep, orig, t_rel, frame, _intr(),

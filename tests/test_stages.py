@@ -34,22 +34,29 @@ for _k in ("JAX_PLATFORMS", "XLA_FLAGS", "HOME"):
 
 
 @pytest.fixture(scope="module")
-def stream():
-    scfg = SYN.StreamConfig(n_frames=N_FRAMES, hw=(FRAME, FRAME), n_obj=4)
-    s, _ = SYN.generate_stream(jax.random.PRNGKey(0), scfg)
-    return s
+def golden():
+    return np.load(GOLDEN)
 
 
 @pytest.fixture(scope="module")
-def chunk(stream):
+def chunk(golden):
+    """The input chunk the goldens were computed from (stored with them,
+    so a change of random-number generator cannot move the goldens)."""
     return api.SensorChunk(
-        stream.frames, stream.poses, stream.gazes, stream.depth
+        *(jnp.asarray(golden[f"input/{name}"])
+          for name in ("frames", "poses", "gazes", "depth"))
     )
 
 
 @pytest.fixture(scope="module")
-def golden():
-    return np.load(GOLDEN)
+def golden_hir_params(golden):
+    """The HIR weights of the ``epic_hir`` golden, stored with it."""
+    like = hir.init_params(jax.random.PRNGKey(7))
+    n = len(jax.tree.leaves(like))
+    return jax.tree.unflatten(
+        jax.tree.structure(like),
+        [jnp.asarray(golden[f"input/hir/{i}"]) for i in range(n)],
+    )
 
 
 def _ecfg(**kw):
@@ -92,10 +99,9 @@ class TestGoldenParity:
         state, stats = jax.jit(comp.step)(comp.init(), chunk)
         _assert_matches_golden(golden, "epic_oracle", state, stats)
 
-    def test_epic_with_hir_model(self, chunk, golden):
+    def test_epic_with_hir_model(self, chunk, golden, golden_hir_params):
         models = P.EPICModels(
-            depth_params=None,
-            hir_params=hir.init_params(jax.random.PRNGKey(7)),
+            depth_params=None, hir_params=golden_hir_params
         )
         comp = api.get_compressor("epic")(_ecfg(), models)
         state, stats = jax.jit(comp.step)(comp.init(), chunk)
