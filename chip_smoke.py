@@ -12,18 +12,29 @@ One chip: 8 synthetic glasses streams at 1024x1024 (the ~1 Mpx sensor of
 adaptive K over the ladder 8/16/24/48).  Each stream sends 3 chunks.
 Mid-run the server is checkpointed while it keeps serving; the
 checkpoint is restored into a fresh server that serves the rest.  The
-same streams are served once more with the compiled ``fused`` Pallas
-backend.  Every served stream is compared with a solo ``EPICCompressor``
-session run on the host CPU device in this process.
+same streams are served once more with each compiled Pallas backend
+(``fused``, ``pallas``, ``pallas_tiled``), whose lowered step must carry
+the Mosaic kernel.  Every served stream is compared with a solo
+``EPICCompressor`` session run on the host CPU device in this process.
+
+The served configuration runs neither learned model: depth comes with
+the sensor's depth track, and every patch is salient, because the HIR
+CNN's 16x16 logit map cannot pool onto the 64x64 patch grid of patch 16
+at 1024x1024.  So the two CNNs those stages call — the HIR saliency CNN
+and the depth CNN, with weights drawn from ``--seed`` — run on their own
+over every served frame, on the chip and on the host CPU, and are
+compared.
 
 Four chips: only the sharded path a four-chip host runs — the flat
 ``StreamServer`` on ``make_stream_mesh(4)``, checkpointed and restored
 onto the mesh — compared with the same streams on one chip.
 
-Tolerance: none.  Retained patches (pixels, timestamps, origins,
-validity, saliency, popularity, last use) and the served counters
-(frames, processed, inserted, buffer occupancy, K trajectory) must be
-bitwise equal to the reference.
+Tolerance: none for serving.  Retained patches (pixels, timestamps,
+origins, validity, saliency, popularity, last use) and the served
+counters (frames, processed, inserted, buffer occupancy, K trajectory)
+must be bitwise equal to the reference.  The learned CNNs: saliency
+logits within ``LOGIT_ATOL`` and depth within ``DEPTH_RTOL`` (relative)
+of the CPU's.
 
 Everything is generated from ``--seed``.  The last line of standard
 output is one JSON object: ``{"ok": true, "device": {"platform": "tpu",
@@ -53,6 +64,8 @@ try:
     import numpy as np
 
     from repro.api import EPICCompressor, SensorChunk
+    from repro.core import depth as depth_mod
+    from repro.core import hir
     from repro.core.pipeline import EPICConfig
     from repro.data import synthetic
     from repro.launch.mesh import make_stream_mesh
@@ -68,6 +81,14 @@ else:
 
 PATCH = 16
 WINDOW = 32
+PALLAS_BACKENDS = ("fused", "pallas", "pallas_tiled")
+HIR_GRID = 16  # the HIR CNN's own logit map
+# The CNNs run at f32 (HIGHEST) precision on both sides.  On a TPU v5e
+# the depth CNN still departs from the CPU's by up to 2.3e-4 relative on
+# this data (the HIR logits by 3.5e-6); a conv left at the TPU's default
+# precision rounds each operand to bf16, up to 2^-9 = 2e-3 relative.
+LOGIT_ATOL = 1e-4
+DEPTH_RTOL = 1e-3
 
 
 class Size(NamedTuple):
@@ -393,8 +414,8 @@ class Verdict:
             _say(f"FAILED: {what}")
             self.failed.append(what)
 
-    def compare(self, got, want, what: str) -> None:
-        lines, ok = compare(got, want, what)
+    def compare(self, got, want, what: str, how=compare) -> None:
+        lines, ok = how(got, want, what)
         for line in lines:
             _say(line)
         self.expect(ok, what)
@@ -427,14 +448,73 @@ class Verdict:
             raise SmokeError("failed: " + "; ".join(self.failed))
 
 
-def fused_has_kernel(cfg: EPICConfig, size: Size, stream: SensorChunk) -> bool:
-    """The ``fused`` step lowered for the default device carries a Mosaic
-    kernel (``tpu_custom_call``), not the Pallas interpreter."""
-    comp = EPICCompressor(
-        cfg._replace(backend="fused", prefilter_k=size.k_ladder[0])
-    )
+def has_kernel(cfg: EPICConfig, size: Size, stream: SensorChunk) -> bool:
+    """The step lowered for the default device carries a Mosaic kernel
+    (``tpu_custom_call``), not the Pallas interpreter."""
+    comp = EPICCompressor(cfg._replace(prefilter_k=size.k_ladder[0]))
     chunk = _chunks(stream, size.chunk_frames)[0]
     return "tpu_custom_call" in jax.jit(comp.step).lower(comp.init(), chunk).as_text()
+
+
+def learned_params(seed: int):
+    """HIR and depth CNN weights drawn from ``seed``."""
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), 1)
+    return (
+        hir.init_params(jax.random.fold_in(key, 0)),
+        depth_mod.init_params(jax.random.fold_in(key, 1)),
+    )
+
+
+def _learned_frame(params, rgb, gaze):
+    """What the saliency and depth stages compute for one frame: the HIR
+    logits on its own grid, and the depth model's full-resolution map."""
+    hir_p, depth_p = params
+    logits = hir.forward(
+        hir_p,
+        depth_mod.resize_image(rgb, hir.HIR_INPUT)[None],
+        hir.gaze_heatmap(gaze, hir.HIR_INPUT, rgb.shape[:2])[None],
+        HIR_GRID,
+    )[0]
+    return logits, depth_mod.predict_fullres(depth_p, rgb)
+
+
+def learned_outputs(params, streams: List[SensorChunk], device) -> List[Tuple]:
+    """The learned CNNs over every frame of every stream, on ``device``."""
+    fn = jax.jit(jax.vmap(_learned_frame, in_axes=(None, 0, 0)))
+    params = jax.device_put(params, device)
+    return [
+        jax.device_get(
+            fn(params, *jax.device_put((s.frames, s.gazes), device))
+        )
+        for s in streams
+    ]
+
+
+def compare_learned(got, want, what: str) -> Tuple[List[str], bool]:
+    """Per stream: saliency logits within ``LOGIT_ATOL``, depth within
+    ``DEPTH_RTOL``; counts the saliency decisions (logit > 0) that differ."""
+    lines, ok = [], len(got) == len(want)
+    for sid, ((lg, dg), (lw, dw)) in enumerate(zip(got, want)):
+        if lg.shape != lw.shape or dg.shape != dw.shape:
+            lines.append(f"{what} stream {sid}: MISMATCH shapes "
+                         f"{lg.shape}/{dg.shape} vs {lw.shape}/{dw.shape}")
+            ok = False
+            continue
+        d_logit = float(np.max(np.abs(lg - lw)))
+        d_depth = float(np.max(np.abs(dg - dw) / np.abs(dw)))
+        flips = int(np.sum((lg > 0) != (lw > 0)))
+        good = (
+            bool(np.all(np.isfinite(lg)) and np.all(np.isfinite(dg)))
+            and d_logit <= LOGIT_ATOL and d_depth <= DEPTH_RTOL
+        )
+        ok &= good
+        lines.append(
+            f"{what} stream {sid}: {'within tolerance' if good else 'MISMATCH'}"
+            f" (max |d logit| {d_logit:.3g}, saliency decisions differing "
+            f"{flips} of {lw.size}, salient {float(np.mean(lw > 0)):.3f}; "
+            f"max rel d depth {d_depth:.3g})"
+        )
+    return lines, ok
 
 
 def _say(line: str) -> None:
@@ -452,8 +532,8 @@ class Timer:
 
 def one_chip(seed: int, size: Size = FULL) -> None:
     """Serve on the default device with ``ref`` (checkpointed and
-    restored mid-run) and with compiled ``fused``; compare both with the
-    CPU reference."""
+    restored mid-run) and with each compiled Pallas backend; compare all
+    with the CPU reference.  Then run the learned CNNs on both."""
     verdict = Verdict()
     with Timer() as t:
         streams = make_streams(seed, size)
@@ -480,16 +560,29 @@ def one_chip(seed: int, size: Size = FULL) -> None:
     verdict.compare(run.results, ref, "serve[ref] vs cpu reference")
     verdict.compare(run.restored, run.results, "restored vs uninterrupted")
 
+    compile_s = run.compile_s
+    for backend in PALLAS_BACKENDS:
+        tag = f"serve[{backend}]"
+        bcfg = cfg._replace(backend=backend)
+        with Timer() as t:
+            run_b = serve(EPICCompressor(bcfg), scfg, streams)
+        _say(f"{tag}: {t.s:.1f} s wall")
+        verdict.run(tag, run_b, total, 1)
+        verdict.compare(run_b.results, ref, f"{tag} vs cpu reference")
+        kernel = has_kernel(bcfg, size, streams[0])
+        _say(f"{backend} step HLO carries tpu_custom_call: {kernel}")
+        verdict.expect(kernel, f"the {backend} step is lowered to a Mosaic kernel")
+        compile_s += run_b.compile_s
+    _say(f"compile seconds (serving phases): {compile_s:.1f}")
+
+    params = learned_params(seed)
     with Timer() as t:
-        run_f = serve(EPICCompressor(cfg._replace(backend="fused")), scfg, streams)
-    _say(f"serve[fused]: {t.s:.1f} s wall")
-    verdict.run("serve[fused]", run_f, total, 1)
-    verdict.compare(run_f.results, ref, "serve[fused] vs cpu reference")
-    verdict.compare(run_f.results, run.results, "serve[fused] vs serve[ref]")
-    kernel = fused_has_kernel(cfg, size, streams[0])
-    _say(f"fused step HLO carries tpu_custom_call: {kernel}")
-    verdict.expect(kernel, "the fused step is lowered to a Mosaic kernel")
-    _say(f"compile seconds (serving phases): {run.compile_s + run_f.compile_s:.1f}")
+        chip = learned_outputs(params, streams, jax.devices()[0])
+    _say(f"learned CNNs on {size.streams * size.frames} frames: {t.s:.1f} s wall")
+    verdict.compare(
+        chip, learned_outputs(params, streams, jax.devices("cpu")[0]),
+        "learned CNNs vs cpu", how=compare_learned,
+    )
     verdict.close()
 
 

@@ -79,7 +79,8 @@ def n_params(params: Params) -> int:
 
 
 def _conv2d(x: Array, w: Array, stride: int = 1, groups: int = 1) -> Array:
-    """NHWC conv with SAME padding."""
+    """NHWC conv with SAME padding, at f32 precision on every platform
+    (a TPU's default would round the operands through bf16)."""
     return jax.lax.conv_general_dilated(
         x,
         w,
@@ -87,6 +88,7 @@ def _conv2d(x: Array, w: Array, stride: int = 1, groups: int = 1) -> Array:
         padding="SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         feature_group_count=groups,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

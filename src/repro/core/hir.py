@@ -70,9 +70,12 @@ def gaze_heatmap(gaze_uv: Array, size: int, frame_hw: tuple,
 
 
 def _conv(x, w, b, stride=1):
+    # HIGHEST: a TPU's default would round the f32 operands through bf16
+    # and move logits near the 0 decision threshold; on CPU it is a no-op.
     out = jax.lax.conv_general_dilated(
         x, w, (stride, stride), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST,
     )
     return out + b
 

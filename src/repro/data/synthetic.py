@@ -244,14 +244,11 @@ def generate_stream(key: Array, cfg: StreamConfig) -> Tuple[Stream, Scene]:
         return rgb, depth, obj, uv
 
     gaze_keys = jax.random.split(k_gaze, cfg.n_frames)
-    xs = (poses, target_pts, gaze_keys)
-    per_batch = max(1, _RENDER_BATCH_PX // (cfg.hw[0] * cfg.hw[1]))
-    if per_batch >= cfg.n_frames:
-        frames, depth, obj_id, gazes = jax.vmap(render_and_gaze)(*xs)
-    else:
-        frames, depth, obj_id, gazes = jax.lax.map(
-            lambda x: render_and_gaze(*x), xs, batch_size=per_batch
-        )
+    frames, depth, obj_id, gazes = jax.lax.map(
+        lambda x: render_and_gaze(*x),
+        (poses, target_pts, gaze_keys),
+        batch_size=max(1, _RENDER_BATCH_PX // (cfg.hw[0] * cfg.hw[1])),
+    )
     return (
         Stream(frames, depth, obj_id, poses, gazes, gaze_target, seg_of_frame),
         scene,

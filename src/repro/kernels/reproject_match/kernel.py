@@ -71,17 +71,18 @@ ROWS = 8
 
 
 def on_platform(kernel_fn, *args, **static):
-    """Call a Pallas entry point compiled on TPU, interpreted elsewhere.
+    """Call a Pallas entry point compiled on TPU, interpreted on CPU.
 
     ``jax.lax.platform_dependent`` resolves the branch when the program is
     lowered, so a program lowered for TPU — on the chip or for a described
     topology — carries the Mosaic kernel, and one lowered for CPU the
-    interpreter.
+    interpreter.  Lowering for any other platform fails: there is no
+    silent interpreter fallback.
     """
     return jax.lax.platform_dependent(
         *args,
         tpu=functools.partial(kernel_fn, **static, interpret=False),
-        default=functools.partial(kernel_fn, **static, interpret=True),
+        cpu=functools.partial(kernel_fn, **static, interpret=True),
     )
 
 

@@ -15,7 +15,11 @@ W is the *within-chunk* inclusive cumsum of w_log (< 0), We the exclusive
 one; every exponent above is <= 0, so the fp32 math is saturation-free
 regardless of decay strength (the factorized r~/k~ trick is not: it splits
 exp(We_t - W_s) into exp(We_t)*exp(-W_s) whose halves can under/overflow
-in opposite directions).
+in opposite directions).  The differences We_t - W_s and W_C - W_s are
+summed directly over their own spans (s, t) and (s, C], never taken as a
+difference of two prefix sums: one strong decay early in the chunk makes
+both prefixes large, and their f32 difference would lose the small decays
+that follow it.
 """
 
 from __future__ import annotations
@@ -60,19 +64,28 @@ def rwkv6_scan_chunked(
 
     W = jnp.cumsum(wc, axis=-2)  # inclusive within-chunk cumsum
     We = W - wc  # exclusive
-    # log-space intra-chunk pair weights; exponent <= 0 for s < t by
-    # construction, min() guards the (unused) upper triangle.
-    expo = jnp.minimum(We[..., :, None, :] - W[..., None, :, :], 0.0)
-    # P[t,s] = sum_k r[t,k] k[s,k] exp(We[t,k]-W[s,k])
+    # log-space intra-chunk pair weights: expo[t,s] = sum_{s<j<t} w_j,
+    # a running sum over t of the decays after s, shifted one step so that
+    # t itself is excluded. Every term is <= 0, and the upper triangle
+    # (masked below) stays 0.
+    mask = jnp.tril(jnp.ones((c, c), bool), k=-1)  # [t, s]: s < t
+    after_s = jnp.where(mask[:, :, None], wc[..., :, None, :], 0.0)
+    incl = jnp.cumsum(after_s, axis=-3)  # sum_{s<j<=t} w_j
+    expo = jnp.concatenate(
+        [jnp.zeros_like(incl[..., :1, :, :]), incl[..., :-1, :, :]], axis=-3
+    )
+    # P[t,s] = sum_k r[t,k] k[s,k] exp(expo[t,s,k])
     p = jnp.einsum("bhntk,bhnsk,bhntsk->bhnts", rc, kc, jnp.exp(expo))
-    mask = jnp.tril(jnp.ones((c, c), bool), k=-1)
     o_intra = jnp.einsum("bhnts,bhnsv->bhntv", jnp.where(mask, p, 0.0), vc)
     bonus = jnp.einsum("bhntk,hk,bhntk->bhnt", rc, uf, kc)
     o_intra = o_intra + bonus[..., None] * vc
 
     r_dec = rc * jnp.exp(We)  # queries decayed to chunk start
     w_last = W[..., -1, :]  # (B,H,nc,K) total chunk decay
-    k_hat = kc * jnp.exp(w_last[..., None, :] - W)  # keys decayed to chunk end
+    # keys decayed to chunk end: sum_{s<j<C} w_j, a reversed exclusive cumsum
+    rev = jnp.cumsum(wc[..., ::-1, :], axis=-2)[..., ::-1, :]  # sum_{j>=s}
+    to_end = jnp.concatenate([rev[..., 1:, :], jnp.zeros_like(rev[..., :1, :])], -2)
+    k_hat = kc * jnp.exp(to_end)
 
     if init_state is None:
         init_state = jnp.zeros((b, h, dk, dv), f32)

@@ -416,8 +416,8 @@ def _restore_one(
     # counters therefore equal the checkpointed ones on both sides.
     for p, st in zip(pools, tree["tiers"]):
         # Onto the pool's own placement: a stream-mesh pool's slot axis
-        # is sharded over its devices (``None`` = the default device).
-        p.states = jax.device_put(st, p._sharding)
+        # is sharded over its devices.
+        p.states = p.place(st)
         p.session_at = [None] * p.capacity
         p._slot_of = {}
     for p, gens in zip(pools, meta["host_generation"]):

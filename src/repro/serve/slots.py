@@ -168,9 +168,13 @@ class SlottedPool:
             active=jnp.zeros((self.capacity,), bool),
             generation=jnp.zeros((self.capacity,), jnp.int32),
         )
-        if self._sharding is not None:
-            states = jax.device_put(states, self._sharding)
-        return states
+        return self.place(states)
+
+    def place(self, tree):
+        """Put a slot-axis-leading pytree where this pool keeps its slot
+        state: sharded over the mesh's slot axis, or on the default
+        device when the pool has no mesh."""
+        return jax.device_put(tree, self._sharding)
 
     # -- slot allocation (host) ----------------------------------------------
 
@@ -458,9 +462,7 @@ class SlottedPool:
     def _all_slots_mask(self) -> Array:
         mask = getattr(self, "_ones_mask", None)
         if mask is None:
-            mask = jnp.ones((self.capacity,), bool)
-            if self._sharding is not None:
-                mask = jax.device_put(mask, self._sharding)
+            mask = self.place(jnp.ones((self.capacity,), bool))
             self._ones_mask = mask
         return mask
 

@@ -121,26 +121,47 @@ def test_verdict_reports_every_failure():
     cs.Verdict().close()
 
 
-def test_cpu_step_carries_no_kernel(cfg, streams):
-    """Lowered for the CPU, the fused backend runs the interpreter."""
-    assert not cs.fused_has_kernel(cfg, TINY, streams[0])
+@pytest.mark.parametrize("backend", cs.PALLAS_BACKENDS)
+def test_cpu_step_carries_no_kernel(backend, cfg, streams):
+    """Lowered for the CPU, a Pallas backend runs the interpreter."""
+    assert not cs.has_kernel(cfg._replace(backend=backend), TINY, streams[0])
+
+
+def test_learned_cnns_compare(streams):
+    """The learned CNNs give finite logits on the HIR grid and full-size
+    depth; a perturbation past the tolerance is reported per stream."""
+    params = cs.learned_params(0)
+    out = cs.learned_outputs(params, streams, cs.jax.devices("cpu")[0])
+    logits, dmap = out[0]
+    assert logits.shape == (TINY.frames, cs.HIR_GRID, cs.HIR_GRID)
+    assert dmap.shape == (TINY.frames, TINY.frame, TINY.frame)
+    lines, ok = cs.compare_learned(out, out, "same")
+    assert ok and all("within tolerance" in x for x in lines)
+    bumped = [(lg + 2 * cs.LOGIT_ATOL, d) for lg, d in out]
+    lines, ok = cs.compare_learned(bumped, out, "bumped")
+    assert not ok and all("MISMATCH" in x for x in lines)
+    deeper = [(lg, d * (1 + 2 * cs.DEPTH_RTOL)) for lg, d in out]
+    assert not cs.compare_learned(deeper, out, "deeper")[1]
 
 
 def test_one_chip_phases_on_the_cpu(capsys):
     """The whole one-chip run at a tiny size: on the CPU every check
-    holds except the Mosaic kernel, which only a TPU lowering carries."""
+    holds except the Mosaic kernels, which only a TPU lowering carries."""
     with pytest.raises(cs.SmokeError) as e:
         cs.one_chip(0, TINY)
-    assert str(e.value) == "failed: the fused step is lowered to a Mosaic kernel"
+    assert str(e.value) == "failed: " + "; ".join(
+        f"the {b} step is lowered to a Mosaic kernel" for b in cs.PALLAS_BACKENDS
+    )
     out = capsys.readouterr().out
     for what in (
         "serve[ref] vs cpu reference",
         "restored vs uninterrupted",
-        "serve[fused] vs cpu reference",
-        "serve[fused] vs serve[ref]",
+        *(f"serve[{b}] vs cpu reference" for b in cs.PALLAS_BACKENDS),
     ):
         for sid in range(TINY.streams):
             assert f"{what} stream {sid}: bitwise equal" in out
+    for sid in range(TINY.streams):
+        assert f"learned CNNs vs cpu stream {sid}: within tolerance" in out
     assert "retraces after warmup 0" in out
 
 

@@ -130,10 +130,10 @@ def test_vmapped_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("backend", ["ref", "fused"])
+@pytest.mark.parametrize("backend", ["ref", *sorted(KERNELS)])
 def test_epic_step_compiles_for_v5e(backend, one_chip):
-    """The whole EPIC step; the ``fused`` backend picks its compiled
-    kernel from the lowering platform, not from the CPU JAX runs on."""
+    """The whole EPIC step; each Pallas backend picks its compiled kernel
+    from the lowering platform, not from the CPU JAX runs on."""
     cfg = P.EPICConfig(
         frame_hw=(FRAME, FRAME), patch=PATCH, capacity=N, window=WINDOW,
         backend=backend,
@@ -149,4 +149,4 @@ def test_epic_step_compiles_for_v5e(backend, one_chip):
         _spec((CHUNK, FRAME, FRAME), one_chip),
     )
     compiled = jax.jit(comp.step).lower(state, chunk).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == (backend == "fused")
+    assert ("tpu_custom_call" in compiled.as_text()) == (backend in KERNELS)
