@@ -176,7 +176,7 @@ def run(quick: bool = False, seed: int = 0, interpret: bool = False) -> Dict:
     except (OSError, json.JSONDecodeError):
         pass
     for row_name in ("serve", "serve[tiered]", "wire", "restore",
-                     "overload", "obs"):
+                     "overload"):
         if row_name in prev_methods:
             methods[row_name] = prev_methods[row_name]
 

@@ -12,9 +12,6 @@
   fault    -> fault_bench       (live-slot checkpoint save/restore + wire
                                  replay latency; merges the `restore` row
                                  into BENCH_core.json)
-  obs      -> obs_bench         (telemetry-on vs -off serve throughput,
-                                 STATUS roundtrip, flight-dump validity;
-                                 merges the `obs` row into BENCH_core.json)
   table1   -> evu_accuracy      (EVU accuracy vs memory, 5 methods)
   figure6  -> energy_model      (system energy + memory, 7 systems)
   ablation -> compression_sweep (motion/bypass/depth ablations)
@@ -44,7 +41,7 @@ def main():
     ap.add_argument(
         "--only", default=None,
         help="comma-separated sub-benchmark names (core,serve,ingest,"
-             "fault,overload,obs,table1,figure6,ablation,roofline)",
+             "fault,overload,table1,figure6,ablation,roofline)",
     )
     args = ap.parse_args()
 
@@ -54,7 +51,7 @@ def main():
     t0 = time.time()
     summary = {}
     known = {
-        "core", "serve", "ingest", "fault", "overload", "obs", "table1",
+        "core", "serve", "ingest", "fault", "overload", "table1",
         "figure6", "ablation", "roofline",
     }
     selected = None if args.only is None else set(args.only.split(","))
@@ -107,15 +104,6 @@ def main():
             name: r["overload_row"][name]["goodput_fps"]
             for name in r["overload_row"]
             if name.startswith("x")
-        }
-    if want("obs"):
-        from benchmarks import obs_bench
-
-        r = obs_bench.run(quick=args.quick)
-        summary["obs_overhead_frac"] = r["overhead_frac"]
-        summary["obs_fps"] = {
-            "off": r["telemetry_off"]["frames_per_sec"],
-            "on": r["telemetry_on"]["frames_per_sec"],
         }
     if want("figure6"):
         from benchmarks import energy_model

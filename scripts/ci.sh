@@ -9,7 +9,7 @@
 #   scripts/ci.sh --wire              # wire ingest-frontier suite
 #   scripts/ci.sh --fault             # checkpoint/restore + crash soak lane
 #   scripts/ci.sh --overload          # degradation + lossy-link soak lane
-#   scripts/ci.sh --obs               # observability suite + overhead guard
+#   scripts/ci.sh --obs               # observability suite
 #   scripts/ci.sh tests/test_api.py   # any extra pytest args pass through
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -136,36 +136,11 @@ fi
 if [[ "${1:-}" == "--obs" ]]; then
   # Observability lane: the repro.obs suite (metrics registry units,
   # histogram merge/percentile pins, flight-recorder Chrome-trace
-  # validity, server span/event integration, STATUS over loopback +
-  # TCP, three-view counter consistency after a lossy overload soak,
-  # k-trajectory ring bound) — then a smoke of the obs bench, which
-  # lands/refreshes the `obs` row of BENCH_core.json and guards the
-  # telemetry-overhead budget + zero-retrace field.
+  # validity, chunk records and the profile clock, server and wire
+  # span integration, STATUS over loopback + TCP, three-view counter
+  # consistency after a lossy overload soak, k-trajectory ring bound).
   shift
-  python -m pytest -q tests/test_obs.py "$@"
-  python -m benchmarks.run --quick --only obs
-  exec python - <<'GUARD'
-import json
-import sys
-
-d = json.load(open("BENCH_core.json"))
-row = d["methods"].get("obs")
-if row is None:
-    sys.exit("BENCH_core.json: obs row missing (obs bench did not land)")
-frac = row.get("overhead_frac")
-if frac is None or frac >= 0.05:
-    sys.exit(f"BENCH_core.json: obs.overhead_frac = {frac!r} — telemetry "
-             "costs >= 5% of telemetry-off throughput")
-n = row.get("post_warmup_retraces")
-if n != 0:
-    sys.exit(f"BENCH_core.json: obs.post_warmup_retraces = {n!r}, "
-             "expected 0 (telemetry retraced the serving path)")
-if row.get("status_ok") is not True:
-    sys.exit("BENCH_core.json: obs.status_ok is not True — the wire "
-             "STATUS roundtrip diverged from host-side collect_status")
-print(f"[obs] row ok: overhead {frac * 100:+.1f}% (< 5%), "
-      "zero retraces, STATUS roundtrip verified")
-GUARD
+  exec python -m pytest -q tests/test_obs.py "$@"
 fi
 
 if [[ "${1:-}" == "--bench-smoke" ]]; then
@@ -274,24 +249,6 @@ if overload.get("post_warmup_retraces") != 0:
 print("[bench-smoke] overload row ok: "
       f"x4 shed={overload.get('x4', {}).get('shed_fraction')}, "
       "deterministic, zero retraces")
-
-# Observability guard: the obs row (refreshed by `ci.sh --obs`,
-# preserved across core rewrites) must be present and within the
-# telemetry-overhead budget — registry counters and span tracing are
-# on the serving hot path, so a silent cost regression shows up here.
-obs = d["methods"].get("obs")
-if obs is None:
-    sys.exit("BENCH_core.json: obs row missing "
-             "(run scripts/ci.sh --obs to land it)")
-ofrac = obs.get("overhead_frac")
-if ofrac is None or ofrac >= 0.05:
-    sys.exit(f"BENCH_core.json: obs.overhead_frac = {ofrac!r} — "
-             "telemetry costs >= 5% of telemetry-off throughput")
-if obs.get("post_warmup_retraces") != 0:
-    sys.exit("BENCH_core.json: obs.post_warmup_retraces = "
-             f"{obs.get('post_warmup_retraces')!r}, expected 0")
-print(f"[bench-smoke] obs row ok: telemetry overhead {ofrac * 100:+.1f}% "
-      "(< 5%), zero retraces")
 GUARD
 fi
 

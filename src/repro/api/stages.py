@@ -24,6 +24,10 @@ Design constraints, in order:
    and tests are unaffected by the refactor.
 3. jit/vmap/scan-friendly: the graph is plain Python composition at
    trace time; nothing here allocates or branches at runtime.
+
+Each stage runs under ``jax.named_scope`` of its name, so the device
+operations of a profile say which stage they belong to (``tsrc``,
+``depth``, ...); the scope changes no operation.
 """
 
 from __future__ import annotations
@@ -136,7 +140,7 @@ class Gated:
             c = ctx._replace(stats={})
             out = []
             for stage, st in zip(self.stages, states):
-                st, c = stage.apply(st, c)
+                st, c = _apply_scoped(stage, st, c)
                 out.append(st)
             return tuple(out), c.stats
 
@@ -145,6 +149,16 @@ class Gated:
 
         states, delta = jax.lax.cond(ctx.process, run, skip, states)
         return states, ctx._replace(stats={**ctx.stats, **delta})
+
+
+def _apply_scoped(stage: FrameStage, state: Any, ctx: FrameCtx):
+    """``stage.apply`` under ``jax.named_scope(stage.name)``, so the
+    stage's device operations carry its name in a profile.  A
+    :class:`Gated` combinator names each of its inner stages instead."""
+    if isinstance(stage, Gated):
+        return stage.apply(state, ctx)
+    with jax.named_scope(stage.name):
+        return stage.apply(state, ctx)
 
 
 class StageGraph:
@@ -266,7 +280,7 @@ class StageGraph:
         )
         out = []
         for stage, st in zip(self.stages, states):
-            st, ctx = stage.apply(st, ctx)
+            st, ctx = _apply_scoped(stage, st, ctx)
             out.append(st)
         stats = self.finalize(ctx) if self.finalize is not None else ctx.stats
         return (tuple(out), self.clock_next(t)), stats

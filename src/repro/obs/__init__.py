@@ -13,11 +13,14 @@ this package — recording must never perturb the serving contracts):
                                               ``server_counters`` and the
                                               latency recorder
   FlightRecorder, NULL_SPAN,
-  TICK_PHASES, EVENT_NAMES         (trace)    per-tick span tracing into a
-                                              bounded ring buffer; dumps
-                                              the last N ticks as Chrome
-                                              trace_event JSON (Perfetto)
-                                              on demand or on crash
+  TICK_PHASES, EVENT_NAMES,
+  self_seconds                     (trace)    per-tick and per-chunk span
+                                              tracing into bounded rings;
+                                              dumps the last N ticks as
+                                              Chrome trace_event JSON
+                                              (Perfetto) on demand or on
+                                              crash, and places the spans
+                                              on a JAX profile's clock
   collect_status, STATUS_SCHEMA    (status)   the host-side truth served
                                               by the wire STATUS frame
                                               (EPWC op 5): occupancy,
@@ -43,6 +46,7 @@ _LAZY = {
     "FlightRecorder": "repro.obs.trace",
     "NULL_SPAN": "repro.obs.trace",
     "TICK_PHASES": "repro.obs.trace",
+    "self_seconds": "repro.obs.trace",
     "EVENT_NAMES": "repro.obs.trace",
     "collect_status": "repro.obs.status",
     "STATUS_SCHEMA": "repro.obs.status",

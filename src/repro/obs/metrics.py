@@ -29,9 +29,7 @@ Everything here is plain host-side Python — no jax imports, no clocks,
 no locks (callers that share a registry across threads serialize on
 their own lock, as ``IngestServer`` already does).  Recording is a dict
 lookup + an integer add, cheap enough that the serve path keeps its
-counters *in* the registry rather than mirroring them into it
-(``benchmarks/obs_bench.py`` gates the total instrumentation overhead
-below 5% of serve throughput).
+counters *in* the registry rather than mirroring them into it.
 
 Metric naming scheme (see ``api/README.md`` "Observability"):
 ``serve_*`` for the ``StreamServer`` tick loop, ``wire_*`` for the

@@ -438,7 +438,7 @@ def _restore_one(
 
         q = ChunkQueue(config.queue_depth, policy=config.queue_policy)
         for chunk in tree["queues"][f"q{i:04d}"]:
-            q._q.append((chunk, now, tick_now))
+            q._q.append((chunk, now, tick_now, None))
             if zero_src is None:
                 zero_src = chunk
         qc = sess["queue_counters"]

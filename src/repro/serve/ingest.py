@@ -104,7 +104,9 @@ class ChunkQueue:
     chunk while ``pop`` keeps the legacy chunk-only signature.
 
     Entries may additionally carry a **logical tick stamp** (``push``'s
-    ``tick`` argument; the server stamps its ``n_ticks``).
+    ``tick`` argument; the server stamps its ``n_ticks``) and the wire
+    ``seq`` the chunk arrived under (``push``'s ``seq``; ``pop_record``
+    hands it back, so the tick that pops the chunk can name it).
     :meth:`shed_stale` drops queued chunks whose stamp has fallen
     behind a staleness deadline — the graceful-degradation
     controller's load-shedding primitive.  Ticks, not wall seconds,
@@ -129,7 +131,9 @@ class ChunkQueue:
         self.maxlen = maxlen
         self.policy = policy
         self.clock = clock
-        self._q: Deque[Tuple[SensorChunk, float, Optional[int]]] = deque()
+        self._q: Deque[
+            Tuple[SensorChunk, float, Optional[int], Optional[int]]
+        ] = deque()
         self.n_pushed = 0
         self.n_overflow = 0
         self.n_dropped = 0
@@ -144,6 +148,7 @@ class ChunkQueue:
         *,
         ts: Optional[float] = None,
         tick: Optional[int] = None,
+        seq: Optional[int] = None,
     ) -> bool:
         if len(self._q) >= self.maxlen:
             if self.policy == "refuse":
@@ -151,7 +156,7 @@ class ChunkQueue:
                 return False
             self._q.popleft()
             self.n_dropped += 1
-        self._q.append((chunk, self.clock() if ts is None else ts, tick))
+        self._q.append((chunk, self.clock() if ts is None else ts, tick, seq))
         self.n_pushed += 1
         return True
 
@@ -166,6 +171,13 @@ class ChunkQueue:
     def pop_full(self) -> Optional[Tuple[SensorChunk, float, Optional[int]]]:
         """Pop ``(chunk, enqueue_ts, enqueue_tick)`` — ``None`` when
         empty; the tick is ``None`` for unstamped pushes."""
+        return self._q.popleft()[:3] if self._q else None
+
+    def pop_record(
+        self,
+    ) -> Optional[Tuple[SensorChunk, float, Optional[int], Optional[int]]]:
+        """Pop ``(chunk, enqueue_ts, enqueue_tick, seq)`` — ``None`` when
+        empty; the seq is ``None`` for pushes without one."""
         return self._q.popleft() if self._q else None
 
     def shed_stale(self, before_tick: int) -> int:

@@ -203,9 +203,10 @@ class StreamServer:
         # exist before the first counter attribute is touched.
         self.metrics = MetricsRegistry()
         # Optional flight recorder (repro.obs.trace.FlightRecorder):
-        # when attached, every tick records its four phase spans and
-        # the stack's discrete events.  ``None`` keeps the hot path at
-        # two attribute reads per would-be span.
+        # when attached, every tick records its phase spans and the
+        # stack's discrete events, and every popped chunk its queue
+        # wait.  ``None`` keeps the hot path at two attribute reads
+        # per would-be span.
         self.recorder: Optional[Any] = None
         if config.k_ladder is not None:
             if not hasattr(getattr(compressor, "cfg", None), "prefilter_k"):
@@ -399,11 +400,19 @@ class StreamServer:
 
     # -- ingest --------------------------------------------------------------
 
-    def submit(self, session_id: Hashable, chunk: SensorChunk) -> bool:
+    def submit(
+        self,
+        session_id: Hashable,
+        chunk: SensorChunk,
+        *,
+        seq: Optional[int] = None,
+    ) -> bool:
         """Queue one chunk for a live stream.
 
         Returns ``False`` (and counts backpressure) when the stream's
         bounded queue is full — the producer should retry after a tick.
+        ``seq`` is the chunk's wire seq, kept beside its enqueue stamp
+        so a recorder can key the chunk's ``queue.wait`` span.
         """
         if chunk.n_frames != self.cfg.chunk_frames:
             raise ValueError(
@@ -415,7 +424,7 @@ class StreamServer:
             raise KeyError(f"session {session_id!r} is not admitted")
         if self._zero_chunk is None:
             self._zero_chunk = jax.tree.map(jnp.zeros_like, chunk)
-        ok = q.push(chunk, tick=self.n_ticks)
+        ok = q.push(chunk, tick=self.n_ticks, seq=seq)
         if not ok:
             self._telemetry[session_id].n_queue_overflow += 1
             self.n_backpressure += 1
@@ -460,13 +469,19 @@ class StreamServer:
         ready = {}
         self._pop_ts = {}
         now = time.monotonic()
+        rec = self.recorder
         for sid in list(self._queues):
             if deferred and self._locate(sid)[0] in deferred:
                 continue
-            entry = self._queues[sid].pop_full()
+            entry = self._queues[sid].pop_record()
             if entry is not None:
                 ready[sid] = entry[0]
                 self._pop_ts[sid] = (entry[1], now)
+                if rec is not None:
+                    rec.chunk_spans(
+                        sid, entry[3], ("queue.wait", entry[1], now),
+                        tick=self.n_ticks,
+                    )
                 if entry[2] is not None:
                     self.max_queue_wait_ticks = max(
                         self.max_queue_wait_ticks, self.n_ticks - entry[2]
@@ -546,15 +561,16 @@ class StreamServer:
 
         with self._span("dispatch"):
             batches: Dict[int, SensorChunk] = {}
-            for tier in {t for t, _ in groups}:
-                rows = [self._zero_chunk] * self._tier_capacity(tier)
-                tp = self._tier_pool(tier)
-                for sid, chunk in ready.items():
-                    if self._locate(sid)[0] == tier:
-                        rows[tp.slot_of(sid)] = chunk
-                batches[tier] = jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *rows
-                )
+            with self._span("stack"):
+                for tier in {t for t, _ in groups}:
+                    rows = [self._zero_chunk] * self._tier_capacity(tier)
+                    tp = self._tier_pool(tier)
+                    for sid, chunk in ready.items():
+                        if self._locate(sid)[0] == tier:
+                            rows[tp.slot_of(sid)] = chunk
+                    batches[tier] = jax.tree.map(
+                        lambda *xs: jnp.stack(xs), *rows
+                    )
 
             stats_parts: Dict[int, List[Any]] = {}
             keys: List[Hashable] = []

@@ -209,18 +209,35 @@ class IngestServer:
         return codec.encode_reply(status, stream_id, seq)
 
     def handle_message(self, msg) -> bytes:
-        """Process one unframed message; returns the encoded reply."""
-        with self.lock:
-            return self._handle_locked(msg)
+        """Process one unframed message; returns the encoded reply.
 
-    def _handle_locked(self, msg) -> bytes:
+        With a flight recorder attached to the stream server, a data
+        frame records ``wire.lock_wait`` (entry to holding the lock) and
+        ``wire.decode`` (the CRC and the parse) under its ``(stream,
+        seq)``."""
+        rec = getattr(self.srv, "recorder", None)
+        if rec is None:
+            with self.lock:
+                return self._handle_locked(msg)
+        t0 = rec.now()
+        with self.lock:
+            return self._handle_locked(msg, rec, t0, rec.now())
+
+    def _handle_locked(self, msg, rec=None, wait0=0.0, wait1=0.0) -> bytes:
         self.n_messages += 1
+        t0 = 0.0 if rec is None else rec.now()
         try:
             kind, frame = codec.decode_message(
                 msg, verify_crc=self.verify_crc
             )
         except codec.WireFormatError:
             return self._nack(codec.NACK_BAD_FRAME, 0)
+        if rec is not None and kind == "data":
+            rec.chunk_spans(
+                frame.stream_id, frame.seq,
+                ("wire.lock_wait", wait0, wait1),
+                ("wire.decode", t0, rec.now()),
+            )
         if kind == "control":
             return self._handle_control(frame)
         if kind != "data":
@@ -253,7 +270,7 @@ class IngestServer:
             self._count_gap(sid, gap)
             return self._nack(codec.NACK_SEQ_GAP, sid, last + 1)
         try:
-            ok = self.srv.submit(sid, frame.chunk)
+            ok = self.srv.submit(sid, frame.chunk, seq=frame.seq)
         except (ValueError, KeyError):
             # Wrong serving quantum / raced an eviction: the frame is
             # structurally valid wire but unserveable as submitted.
@@ -354,8 +371,14 @@ class IngestServer:
 
     def tick(self):
         """Run one serving tick under the ingest lock (safe alongside
-        socket receivers); prunes wire sessions the tick evicted."""
+        socket receivers); prunes wire sessions the tick evicted.  With
+        a recorder attached, the wait for the lock is the tick's
+        ``lock_wait`` span."""
+        rec = getattr(self.srv, "recorder", None)
+        t0 = 0.0 if rec is None else rec.now()
         with self.lock:
+            if rec is not None:
+                rec.carry_span("lock_wait", t0, rec.now())
             stepped = self.srv.tick()
             live = set(self.srv.live_sessions)
             for sid in [s for s in self._seq_seen if s not in live]:

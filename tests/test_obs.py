@@ -44,7 +44,7 @@ from repro.obs.metrics import (
     gauge_property,
 )
 from repro.obs.status import collect_status
-from repro.obs.trace import NULL_SPAN, FlightRecorder
+from repro.obs.trace import NULL_SPAN, FlightRecorder, self_seconds
 from repro.runtime.fault import FailureInjector, WorkerFailure
 from repro.serve import ServerConfig, StreamServer
 from repro.serve.adaptive import KLadderController
@@ -377,6 +377,236 @@ class TestFlightRecorder:
             FlightRecorder(capacity=0)
 
 
+class TestChunkRecords:
+    def test_chunk_ring_is_bounded_and_keyed(self):
+        rec = FlightRecorder(capacity=3, clock=_FakeClock())
+        for seq in range(10):
+            rec.chunk_spans("s", seq, ("wire.decode", seq, seq + 0.5))
+        chunks = rec.chunks()
+        assert [c["seq"] for c in chunks] == [7, 8, 9]
+        assert rec.n_chunks_recorded == 10
+        assert len(rec._chunk_of) == 3  # the key index falls off too
+        # a later span of a retained key joins its record; the pop
+        # closes it and lists it in the open tick
+        rec.begin_tick(0)
+        rec.chunk_spans("s", 9, ("queue.wait", 10.0, 11.0), tick=0)
+        rec.end_tick()
+        (tick,) = rec.ticks()
+        assert tick["chunks"] == [chunks[-1]]
+        assert chunks[-1]["tick"] == 0
+        assert [n for n, _, _ in chunks[-1]["spans"]] == [
+            "wire.decode", "queue.wait",
+        ]
+        # a reused key after the pop (a reopened stream) is a new record
+        rec.chunk_spans("s", 9, ("wire.decode", 12.0, 13.0))
+        assert len(rec.chunks()) == 3 and rec.chunks()[-1]["tick"] is None
+
+    def test_chunk_ring_under_concurrent_writers(self):
+        """Socket threads record chunk spans while a tick thread pops:
+        no record is lost from the counts, the ring stays bounded and
+        the key index only names retained, unpopped records."""
+        import sys
+        import threading
+
+        rec = FlightRecorder(capacity=64)
+        n_writers, per_writer = 2 * (os.cpu_count() or 2), 300
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            def write(w):
+                for seq in range(per_writer):
+                    rec.chunk_spans(w, seq, ("wire.decode", 0.0, 1.0))
+
+            def pop():
+                for t in range(per_writer):
+                    rec.begin_tick(t)
+                    for w in range(n_writers):
+                        rec.chunk_spans(w, t, ("queue.wait", 1.0, 2.0), tick=t)
+                    rec.end_tick()
+
+            threads = [threading.Thread(target=write, args=(w,))
+                       for w in range(n_writers)]
+            threads.append(threading.Thread(target=pop))
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=60)
+            assert not any(th.is_alive() for th in threads)
+        finally:
+            sys.setswitchinterval(old)
+        chunks = rec.chunks()
+        assert len(chunks) == 64
+        assert rec.n_spans == 2 * n_writers * per_writer
+        ring = {id(c) for c in chunks}
+        assert all(id(r) in ring and r["tick"] is None
+                   for r in rec._chunk_of.values())
+
+    def test_chunks_without_a_seq_never_merge(self):
+        rec = FlightRecorder(capacity=4, clock=_FakeClock())
+        rec.chunk_spans("s", None, ("queue.wait", 0.0, 1.0), tick=0)
+        rec.chunk_spans("s", None, ("queue.wait", 1.0, 2.0), tick=1)
+        assert len(rec.chunks()) == 2 and not rec._chunk_of
+
+    def test_carried_span_opens_the_next_tick(self):
+        rec = FlightRecorder(capacity=4, clock=_FakeClock())
+        rec.carry_span("lock_wait", 0.25, 0.5)
+        rec.begin_tick(0)
+        rec.end_tick()
+        rec.begin_tick(1)
+        rec.end_tick()
+        t0, t1 = rec.ticks()
+        assert t0["spans"] == [("lock_wait", 0.25, 0.5)] and t1["spans"] == []
+
+    def test_chunk_spans_are_dumped_on_their_own_track(self):
+        rec = FlightRecorder(capacity=4, clock=_FakeClock())
+        rec.chunk_spans(("sess", 1), 5, ("wire.decode", 1.0, 3.0))
+        doc = json.loads(json.dumps(rec.to_chrome_trace()))
+        (ev,) = [e for e in doc["traceEvents"] if e.get("cat") == "chunk"]
+        assert (ev["name"], ev["ts"], ev["dur"], ev["tid"]) == (
+            "wire.decode", 1e6, 2e6, 3,
+        )
+        assert ev["args"] == {"stream": "('sess', 1)", "seq": 5, "tick": None}
+        assert doc["otherData"]["chunks_retained"] == 1
+
+    def test_spans_on_profile_maps_each_tick_by_its_anchor(self):
+        rec = FlightRecorder(capacity=4)
+        rec.begin_tick(0)
+        with rec.span("dispatch"):
+            pass
+        rec.end_tick()
+        rec.chunk_spans(1, 0, ("wire.decode", rec.now(), rec.now()))
+        (tick,) = rec.ticks()
+        (_, s0, s1), = tick["spans"]
+        start_ns = tick["anchor_ns"] + int(s0 * 1e9) - 1_000_000
+        spans = rec.spans_on_profile(start_ns, 0.0, 10.0)
+        assert [s[0] for s in spans] == ["tick.dispatch", "wire.decode"]
+        name, p0, p1, ids = spans[0]
+        assert p0 == pytest.approx(1e-3, abs=1e-6)
+        assert p1 - p0 == pytest.approx(s1 - s0, abs=1e-9)
+        assert ids == {"tick": 0}
+        assert spans[1][3] == {"stream": 1, "seq": 0, "tick": None}
+        # a window that ends before the spans selects none
+        assert rec.spans_on_profile(start_ns, 0.0, 0.5e-3) == []
+
+    def test_self_seconds_credit_the_child_inside_its_parent(self):
+        tick = {"tick": 3}
+        spans = [
+            ("tick.dispatch", 0.0, 1.0, tick),
+            ("tick.stack", 0.1, 0.9, tick),  # child: outweighs its parent
+            ("wire.decode", 0.2, 0.6, {"stream": 0, "seq": 4, "tick": None}),
+        ]
+        got = self_seconds(spans, 0.0, 1.0)
+        assert got["tick.dispatch"] == pytest.approx(0.2)
+        assert got["tick.stack"] == pytest.approx(0.8)
+        assert got["wire.decode"] == pytest.approx(0.4)  # another thread
+        inside = self_seconds(spans, 0.5, 2.0)
+        assert inside["tick.dispatch"] == pytest.approx(0.1)
+        assert inside["tick.stack"] == pytest.approx(0.4)
+
+    def test_shared_clock_with_the_profiler(self, tmp_path):
+        """A recorder span and a ``TraceAnnotation`` inside it land
+        within 1 ms of each other on the profile's clock."""
+        from jax.profiler import ProfileData, TraceAnnotation
+
+        rec = FlightRecorder(capacity=4)
+        opts = jax.profiler.ProfileOptions()
+        opts.host_tracer_level = 1
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            rec.begin_tick(0)
+            with rec.span("dispatch"):
+                with TraceAnnotation("repro.obs.clock_probe"):
+                    sum(range(200_000))
+            rec.end_tick()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = [
+            os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+            for f in fs if f.endswith(".xplane.pb")
+        ]
+        prof = ProfileData.from_file(path)
+        start_ns = None
+        probe = []
+        for plane in prof.planes:
+            if plane.name == "Task Environment":
+                start_ns = int(dict(plane.stats)["profile_start_time"])
+            for line in plane.lines:
+                probe += [
+                    (e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
+                    for e in line.events if e.name == "repro.obs.clock_probe"
+                ]
+        assert start_ns is not None and len(probe) == 1
+        (a0, a1), = probe
+        (name, s0, s1, _), = rec.spans_on_profile(start_ns, 0.0, 1e9)
+        assert name == "tick.dispatch"
+        assert abs(s0 - a0) < 1e-3 and abs(s1 - a1) < 1e-3
+
+
+def _wire_run(recorder):
+    """Two streams, two data frames each, over loopback; two ingest
+    ticks.  Returns the served states and the sent keys."""
+    srv = _server(capacity=2, queue_depth=2)
+    srv.recorder = recorder
+    ingest = IngestServer(srv)
+    loop = Loopback(ingest)
+    chunks = _sensor_chunks(2, n_frames=16)
+    sent = []
+    for sid in (3, 5):
+        assert loop.send(codec.encode_control(codec.OP_OPEN, sid)).ok
+    for seq, c in enumerate(chunks):
+        for sid in (3, 5):
+            assert loop.send(codec.encode_chunk(
+                c, stream_id=sid, seq=10 + seq, timestamp_ns=seq
+            )).ok
+            sent.append((sid, 10 + seq))
+    ingest.tick()
+    ingest.tick()
+    states = {sid: jax.device_get(srv.export(sid)) for sid in (3, 5)}
+    return srv, states, sent
+
+
+class TestWireTracing:
+    def test_ingest_tick_leaves_chunk_and_tick_spans(self):
+        rec = FlightRecorder(capacity=16)
+        srv, _, sent = _wire_run(rec)
+        chunks = rec.chunks()
+        assert [(c["stream"], c["seq"]) for c in chunks] == sent
+        for c in chunks:
+            assert [n for n, _, _ in c["spans"]] == [
+                "wire.lock_wait", "wire.decode", "queue.wait",
+            ]
+            (_, w0, w1), (_, d0, d1), (_, q0, q1) = c["spans"]
+            assert w0 <= w1 <= d0 <= d1 <= q0 <= q1
+        ticks = rec.ticks()
+        assert [c["tick"] for c in chunks] == [0, 0, 1, 1]
+        for tk in ticks:
+            names = [n for n, _, _ in tk["spans"]]
+            assert names[0] == "lock_wait" and names.count("stack") == 1
+            spans = {n: (a, b) for n, a, b in tk["spans"]}
+            (l0, l1), (t0, _) = spans["lock_wait"], spans["ingest"]
+            assert l0 <= l1 <= t0
+            (a, b), (c0, c1) = spans["dispatch"], spans["stack"]
+            assert a <= c0 <= c1 <= b  # stack nests inside dispatch
+            assert [(c["stream"], c["seq"]) for c in tk["chunks"]] == (
+                sent[:2] if tk["tick"] == 0 else sent[2:]
+            )
+
+    def test_detached_recorder_records_nothing_and_serves_the_same(self):
+        rec = FlightRecorder(capacity=16)
+        _, traced, _ = _wire_run(rec)
+        n_spans = rec.n_spans
+        srv, plain, _ = _wire_run(None)
+        assert srv.recorder is None
+        # nothing of the detached run reached the recorder
+        assert (rec.n_spans, rec.n_chunks_recorded) == (n_spans, 4)
+        assert len(rec.ticks()) == 2
+        for sid in traced:
+            for a, b in zip(jax.tree.leaves(traced[sid]),
+                            jax.tree.leaves(plain[sid])):
+                assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
 # ---------------------------------------------------------------------------
 # StreamServer integration: phase spans, events, registry == views
 # ---------------------------------------------------------------------------
@@ -394,7 +624,9 @@ class TestServerTracing:
         ticks = srv.recorder.ticks()
         assert len(ticks) == len(chunks)
         span_names = {s[0] for t in ticks for s in t["spans"]}
-        assert span_names == {"ingest", "schedule", "dispatch", "readback"}
+        assert span_names == {
+            "ingest", "schedule", "dispatch", "stack", "readback",
+        }
         events = [e[0] for t in ticks for e in t["events"]]
         assert events.count("admit") == 0  # admit happened pre-tick 0
         srv.close("a")
