@@ -163,8 +163,11 @@ def probe(cfg, mix, *, seed: int, seconds: float, logdir: str) -> dict:
     rec = FlightRecorder(capacity=1 << 16)
     prof = Profiles(rec, logdir, mix["warmup_ticks"] + 5, min(3.0, seconds / 5))
 
+    served = []
+
     def attach(srv):
         srv.recorder = rec
+        served.append(srv)
         prof.thread.start()
 
     e2e = [{"name": "frames_per_s", "unit": "frames/s"}, {"name": "setup_s", "unit": "s"}]
@@ -184,6 +187,11 @@ def probe(cfg, mix, *, seed: int, seconds: float, logdir: str) -> dict:
     line = {
         "correct": out.correct, "checks": out.checks, "metrics": out.metrics,
         "device": out.device,
+        # Whole run, warm-up and drain included.
+        "wire_counters": {
+            name: served[0].metrics.value(name)
+            for name in ("wire_frames_in_total", "wire_frames_during_step_total")
+        },
         "untraced": {
             "frames_per_s": frames_per_s(ticks, cf, first, prof.marks["before"]),
             "ticks_per_s": len(stepped) / (prof.marks["before"] - first),

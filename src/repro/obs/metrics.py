@@ -26,8 +26,12 @@ source of truth underneath them:
   exposition format.
 
 Everything here is plain host-side Python — no jax imports, no clocks,
-no locks (callers that share a registry across threads serialize on
-their own lock, as ``IngestServer`` already does).  Recording is a dict
+no locks.  Callers that share a registry across threads serialize on
+their own locks: a ``StreamServer`` and its ``IngestServer`` bump the
+``wire_*`` counters and ``serve_backpressure_total`` under the stream
+server's queue lock and the other ``serve_*`` counters under its pool
+lock, so each counter has one writer's lock, and a STATUS snapshot
+holds both.  Recording is a dict
 lookup + an integer add, cheap enough that the serve path keeps its
 counters *in* the registry rather than mirroring them into it.
 

@@ -8,9 +8,10 @@ counter views, and the full ``STATUS_REASONS`` table so a client can
 render every NACK it will ever receive without a second lookup.
 
 The ingest server serves it over the wire as the ``STATUS`` control
-frame (EPWC op 5, see :mod:`repro.wire.codec`): the caller already
-holds the ingest lock when the handler runs, so the snapshot is
-consistent with respect to concurrent submits and ticks.  This module
+frame (EPWC op 5, see :mod:`repro.wire.codec`): the handler runs
+holding both of the stream server's locks (``StreamServer.locked``),
+so the snapshot is consistent with respect to concurrent submits and
+ticks.  This module
 closes the ROADMAP item "surfacing STATUS_REASONS + credit state
 through a server status/introspection endpoint".
 
@@ -43,8 +44,9 @@ def _tier_occupancy(srv) -> list:
 def collect_status(ingest) -> Dict[str, Any]:
     """One consistent, JSON-safe snapshot of an ingest frontier.
 
-    Call with the ingest lock held (the wire STATUS handler does; a
-    host-side caller that is the only thread may call it bare).
+    Call inside ``ingest.srv.locked()`` (the wire STATUS handler
+    does; a host-side caller that is the only thread may call it
+    bare).
     """
     from repro.wire import codec  # wire is an optional layer elsewhere
 

@@ -3,21 +3,25 @@
 The serving tick has four phases — **ingest** (degrade policy + queue
 pops), **schedule** (the rung scheduler's plan), **dispatch** (the
 masked pool steps) and **readback** (the tick's single batched
-``device_get``) — plus two more spans: **lock_wait** (the ingest
-server's wait for its lock before the tick, recorded into the tick that
-follows) and **stack** (inside ``dispatch``: the row assembly and the
-host-to-device stack of the tick's batch).  Discrete events are
-scattered through the stack: admit/evict, promote/demote/swap
-migrations, rung changes, degrade level transitions,
-checkpoint/resume, and wire NACKs.
+``device_get``) — plus two more spans: **lock_wait** (``IngestServer.
+tick`` from entry to holding the stream server's pool and queue locks,
+before the tick's pops, recorded into the tick that follows; the queue
+lock is released again before ``schedule``, so this wait is for
+control frames and submits, not for a step) and **stack** (inside
+``dispatch``: the row assembly and the host-to-device stack of the
+tick's batch).  Discrete events are scattered through the stack:
+admit/evict, promote/demote/swap migrations, rung changes, degrade
+level transitions, checkpoint/resume, and wire NACKs.
 
 A chunk's way in is recorded apart from the ticks, keyed by its wire
-``(stream, seq)``: **wire.lock_wait** (``IngestServer.handle_message``
-waiting for the ingest lock), **wire.decode** (``codec.decode_message``:
-the CRC and the parse) and **queue.wait** (enqueue to the pop of the
-tick that steps it).  Spans from the socket threads so never fall into
-whichever tick happens to be open; the popping tick lists the chunk
-records it popped under ``"chunks"``.
+``(stream, seq)``: **wire.decode** (``codec.decode_message``: the CRC
+and the parse, run before any lock), **wire.lock_wait** (``IngestServer.
+handle_message`` from the end of the decode to holding the stream
+server's queue lock, which no tick holds across its step) and
+**queue.wait** (enqueue to the pop of the tick that steps it).  Spans
+from the socket threads so never fall into whichever tick happens to be
+open; the popping tick lists the chunk records it popped under
+``"chunks"``.
 
 :class:`FlightRecorder` records all of it host-side into two bounded
 rings — ticks, and chunk records, each ``capacity`` long (old entries

@@ -45,6 +45,7 @@ seqs after it are replayed from the client windows).
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, Hashable, List, NamedTuple, Optional, Tuple
 
@@ -79,6 +80,7 @@ _WIRE_COUNTER_ATTRS = (
     "n_closed",
     "n_resumed",
     "n_dup_suppressed",
+    "n_frames_during_step",
 )
 
 
@@ -172,18 +174,18 @@ def snapshot_server(
     captured reference would be invalidated by the very next tick while
     an asynchronous save is still reading it.  The copies are
     asynchronous device dispatches, so the tick path never waits for a
-    host transfer.  With ``ingest`` given, its lock is held while
-    capturing so a socket thread cannot interleave a submit
-    mid-snapshot, and the wire seq cursors are included.
+    host transfer.  Both of the server's locks are held while
+    capturing (``StreamServer.locked``): no tick's step is in flight (a
+    snapshot never copies state a step is about to donate) and no
+    socket thread interleaves a submit.  With ``ingest`` given, the
+    wire seq cursors are included.
     """
-    if ingest is not None:
-        if ingest.srv is not server:
-            raise ValueError(
-                "ingest frontier is bound to a different StreamServer"
-            )
-        with ingest.lock:
-            return _snapshot_locked(server, ingest)
-    return _snapshot_locked(server, None)
+    if ingest is not None and ingest.srv is not server:
+        raise ValueError(
+            "ingest frontier is bound to a different StreamServer"
+        )
+    with server.locked():
+        return _snapshot_locked(server, ingest)
 
 
 def _snapshot_locked(
@@ -338,18 +340,27 @@ def restore_server(
     gc) falls back to the previous complete one, exactly like
     :func:`repro.checkpoint.store.restore`.
     """
-    if step is not None:
-        return _restore_one(directory, step, compressor, server, with_ingest)
-    steps = store.complete_steps(directory)
-    if not steps:
-        raise FileNotFoundError(f"no complete checkpoint in {directory}")
-    last_err: Optional[BaseException] = None
-    for s in reversed(steps):
-        try:
-            return _restore_one(directory, s, compressor, server, with_ingest)
-        except store._DAMAGED_STEP_ERRORS as e:
-            last_err = e
-    raise last_err
+    # Into a given server, under both of its locks: a restore never
+    # rebinds slots under a tick or a submit.
+    with contextlib.nullcontext() if server is None else server.locked():
+        if step is not None:
+            return _restore_one(
+                directory, step, compressor, server, with_ingest
+            )
+        steps = store.complete_steps(directory)
+        if not steps:
+            raise FileNotFoundError(
+                f"no complete checkpoint in {directory}"
+            )
+        last_err: Optional[BaseException] = None
+        for s in reversed(steps):
+            try:
+                return _restore_one(
+                    directory, s, compressor, server, with_ingest
+                )
+            except store._DAMAGED_STEP_ERRORS as e:
+                last_err = e
+        raise last_err
 
 
 def _restore_one(
@@ -505,7 +516,8 @@ def _restore_one(
                 int(k): int(v) for k, v in w["seq_gaps"]
             }
             for a in _WIRE_COUNTER_ATTRS:
-                setattr(ingest, a, w["counters"][a])
+                # a counter newer than the checkpoint starts at 0
+                setattr(ingest, a, w["counters"].get(a, 0))
             ingest.nacks = dict(w["nacks"])
     rec = getattr(srv, "recorder", None)
     if rec is not None:

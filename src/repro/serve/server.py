@@ -44,14 +44,35 @@ host sync regardless of how many tiers stepped
 readbacks into a single ``device_get``).
 
 Eviction policies: ``"explicit"`` (only :meth:`close`), ``"idle"``
-(streams idle ≥ ``idle_frames`` frames are closed at tick end), and
-``"lru"`` (a full pool evicts the least-recently-stepped stream to
-admit a new one).
+(streams idle ≥ ``idle_frames`` frames with nothing queued are closed
+at tick end), and ``"lru"`` (a full pool evicts the least-recently-
+stepped stream to admit a new one).
+
+**Locks.**  Chunks may be submitted from other threads than the one
+that ticks (:class:`~repro.wire.server.IngestServer` does), so the
+server owns two reentrant locks and takes them itself, always in this
+order: ``pool_lock``, then ``queue_lock``.
+
+* ``queue_lock`` guards the queue table and every ``ChunkQueue``:
+  :meth:`submit`, a tick's pops and its idle evictions take it.
+* ``pool_lock`` serialises the pool: :meth:`tick` holds it throughout,
+  :meth:`admit`, :meth:`close` and :meth:`locked` take it, so nothing
+  reads or replaces pool or slot state under a step that is about to
+  donate it.
+
+A tick has two phases, :meth:`tick_pop` (degrade policy, one pop per
+stream, under both locks) and :meth:`tick_step` (schedule, stack,
+device step, readback, telemetry, under the pool lock alone);
+:meth:`tick` runs them back to back, so a submit never waits for a
+device step.  :meth:`locked` holds both, for a caller that composes
+several operations (a wire control frame, a checkpoint snapshot).
 """
 
 from __future__ import annotations
 
+import contextlib
 import operator
+import threading
 import time
 from functools import reduce
 from typing import (
@@ -208,6 +229,13 @@ class StreamServer:
         # wait.  ``None`` keeps the hot path at two attribute reads
         # per would-be span.
         self.recorder: Optional[Any] = None
+        # The two locks of the module docstring, taken in this order.
+        # Reentrant: close() runs inside a tick (idle policy) and
+        # admit() (lru policy), and a caller holding locked() may tick.
+        self.pool_lock = threading.RLock()
+        self.queue_lock = threading.RLock()
+        # True while a tick's step phase runs outside the queue lock.
+        self.stepping = False
         if config.k_ladder is not None:
             if not hasattr(getattr(compressor, "cfg", None), "prefilter_k"):
                 raise ValueError(
@@ -329,39 +357,40 @@ class StreamServer:
         stream to make room; other policies raise ``RuntimeError``
         when full.  Returns the (global) slot.
         """
-        if session_id in self._queues:
-            # Must precede the LRU branch: a duplicate admit on a full
-            # pool must not evict an innocent stream (or silently reset
-            # the duplicate itself).
-            raise ValueError(f"session {session_id!r} already admitted")
-        if not self.pool.free_slots():
-            if self.cfg.eviction == "lru":
-                self.close(self._lru_session())
-            else:
-                self.n_admit_rejected += 1
-                raise RuntimeError(
-                    f"pool full ({self.cfg.capacity} slots); close a "
-                    f"stream or use the 'lru' eviction policy"
-                )
-        slot = self.pool.admit(session_id)
-        self._queues[session_id] = ChunkQueue(
-            self.cfg.queue_depth, policy=self.cfg.queue_policy
-        )
-        if self.cfg.k_ladder is not None:
-            self._controllers[session_id] = self._make_controller(
-                self.compressor, self.cfg
+        with self.locked():
+            if session_id in self._queues:
+                # Must precede the LRU branch: a duplicate admit on a full
+                # pool must not evict an innocent stream (or silently reset
+                # the duplicate itself).
+                raise ValueError(f"session {session_id!r} already admitted")
+            if not self.pool.free_slots():
+                if self.cfg.eviction == "lru":
+                    self.close(self._lru_session())
+                else:
+                    self.n_admit_rejected += 1
+                    raise RuntimeError(
+                        f"pool full ({self.cfg.capacity} slots); close a "
+                        f"stream or use the 'lru' eviction policy"
+                    )
+            slot = self.pool.admit(session_id)
+            self._queues[session_id] = ChunkQueue(
+                self.cfg.queue_depth, policy=self.cfg.queue_policy
             )
-        tier = self.pool.unpack_slot(slot)[0] if self._tiered else 0
-        self._telemetry[session_id] = StreamTelemetry(
-            session_id=session_id,
-            slot=slot,
-            generation=self.pool.generation_of(slot),
-            admitted_tick=self.n_ticks,
-            tier=tier,
-        )
-        self.n_admitted += 1
-        self._event("admit", stream=session_id, slot=slot, tier=tier)
-        return slot
+            if self.cfg.k_ladder is not None:
+                self._controllers[session_id] = self._make_controller(
+                    self.compressor, self.cfg
+                )
+            tier = self.pool.unpack_slot(slot)[0] if self._tiered else 0
+            self._telemetry[session_id] = StreamTelemetry(
+                session_id=session_id,
+                slot=slot,
+                generation=self.pool.generation_of(slot),
+                admitted_tick=self.n_ticks,
+                tier=tier,
+            )
+            self.n_admitted += 1
+            self._event("admit", stream=session_id, slot=slot, tier=tier)
+            return slot
 
     @staticmethod
     def _make_controller(compressor, config: ServerConfig):
@@ -382,15 +411,23 @@ class StreamServer:
 
     def close(self, session_id: Hashable) -> StreamTelemetry:
         """Explicitly evict a stream; returns its final telemetry."""
-        self.pool.evict_session(session_id)
-        self._n_dropped_closed += self._queues[session_id].n_dropped
-        self._queues.pop(session_id)
-        self._controllers.pop(session_id, None)
-        tele = self._telemetry.pop(session_id)
-        self.evicted.append(tele)
-        self.n_evicted += 1
-        self._event("evict", stream=session_id, tier=tele.tier)
-        return tele
+        with self.locked():
+            self.pool.evict_session(session_id)
+            self._n_dropped_closed += self._queues[session_id].n_dropped
+            self._queues.pop(session_id)
+            self._controllers.pop(session_id, None)
+            tele = self._telemetry.pop(session_id)
+            self.evicted.append(tele)
+            self.n_evicted += 1
+            self._event("evict", stream=session_id, tier=tele.tier)
+            return tele
+
+    @contextlib.contextmanager
+    def locked(self):
+        """Hold the pool lock, then the queue lock: no tick's step is in
+        flight and no submit interleaves until the block ends."""
+        with self.pool_lock, self.queue_lock:
+            yield
 
     def _lru_session(self) -> Hashable:
         return min(
@@ -419,16 +456,17 @@ class StreamServer:
                 f"serving quantum is {self.cfg.chunk_frames} frames per "
                 f"chunk, got {chunk.n_frames} (pad or re-chunk upstream)"
             )
-        q = self._queues.get(session_id)
-        if q is None:
-            raise KeyError(f"session {session_id!r} is not admitted")
-        if self._zero_chunk is None:
-            self._zero_chunk = jax.tree.map(jnp.zeros_like, chunk)
-        ok = q.push(chunk, tick=self.n_ticks, seq=seq)
-        if not ok:
-            self._telemetry[session_id].n_queue_overflow += 1
-            self.n_backpressure += 1
-        return ok
+        with self.queue_lock:
+            q = self._queues.get(session_id)
+            if q is None:
+                raise KeyError(f"session {session_id!r} is not admitted")
+            if self._zero_chunk is None:
+                self._zero_chunk = jax.tree.map(jnp.zeros_like, chunk)
+            ok = q.push(chunk, tick=self.n_ticks, seq=seq)
+            if not ok:
+                self._telemetry[session_id].n_queue_overflow += 1
+                self.n_backpressure += 1
+            return ok
 
     # -- tracing hooks -------------------------------------------------------
 
@@ -673,8 +711,15 @@ class StreamServer:
             )
         self.n_ticks += 1
         if self.cfg.eviction == "idle":
-            for sid in list(self._telemetry):
-                if self._telemetry[sid].idle_frames >= self.cfg.idle_frames:
+            # Under the queue lock, and only streams with nothing
+            # queued: a chunk accepted while this tick stepped is
+            # served by the next one, never dropped with its stream.
+            with self.queue_lock:
+                for sid in [
+                    sid for sid, tele in self._telemetry.items()
+                    if tele.idle_frames >= self.cfg.idle_frames
+                    and not len(self._queues[sid])
+                ]:
                     self.close(sid)
         if self._tiered:
             self._rebalance()
@@ -757,15 +802,48 @@ class StreamServer:
 
     # -- tick / drain --------------------------------------------------------
 
-    def tick(self) -> List[Hashable]:
+    def tick(self, *, wait_since: Optional[float] = None) -> List[Hashable]:
         """Serve one tick: step every stream with a pending chunk.
 
-        Returns the session ids stepped this tick.  A tick with no
-        pending work still advances the clock and the idle accounting.
+        Holds the pool lock throughout and the queue lock for the pops
+        only, so chunks are submitted while the step runs.  Returns the
+        session ids stepped this tick.  A tick with no pending work
+        still advances the clock and the idle accounting.
+
+        ``wait_since`` is a reading of the attached recorder's clock
+        (``recorder.now()``) taken by a caller that ticks alongside
+        submitting threads: the wait from then to holding both locks
+        is recorded as the tick's ``lock_wait`` span.
         """
-        self._tick_begin()
-        with self._span("ingest"):
-            ready = self._pop_ready(self._degrade_step())
+        with self.pool_lock:
+            with self.queue_lock:
+                rec = self.recorder
+                if rec is not None and wait_since is not None:
+                    rec.carry_span("lock_wait", wait_since, rec.now())
+                ready = self.tick_pop()
+                self.stepping = True
+            try:
+                return self.tick_step(ready)
+            finally:
+                self.stepping = False
+
+    def tick_pop(self) -> Dict[Hashable, SensorChunk]:
+        """A tick's first phase: open its record, apply the degrade
+        policy and pop at most one pending chunk per stream, under the
+        queue lock.  The caller holds the pool lock across this phase
+        and :meth:`tick_step`."""
+        with self.queue_lock:
+            self._tick_begin()
+            with self._span("ingest"):
+                return self._pop_ready(self._degrade_step())
+
+    def tick_step(
+        self, ready: Dict[Hashable, SensorChunk]
+    ) -> List[Hashable]:
+        """A tick's second phase, on what :meth:`tick_pop` returned:
+        schedule, stack, device step and readback, then telemetry,
+        controllers, idle eviction and rebalancing.  Needs the pool
+        lock only.  Returns the session ids stepped."""
         if not ready:
             self._finish({}, {})
             return []
@@ -790,25 +868,24 @@ class StreamServer:
         of ticks run.
         """
         iters = {sid: iter(src) for sid, src in feeds.items()}
-        for sid in iters:
-            if sid not in self._queues:
-                self.admit(sid)
-        ticks = 0
-        self._refill(iters)
-        while iters or any(len(q) for q in self._queues.values()):
-            self._tick_begin()
-            with self._span("ingest"):
-                ready = self._pop_ready(self._degrade_step())
-            inflight = self._dispatch(ready) if ready else None
-            self._refill(iters)  # overlaps the dispatched compute
-            if inflight is not None:
-                self._finish(*inflight)
-            else:
-                self._finish({}, {})
-            ticks += 1
-            if max_ticks is not None and ticks >= max_ticks:
-                break
-        return ticks
+        with self.pool_lock:
+            for sid in iters:
+                if sid not in self._queues:
+                    self.admit(sid)
+            ticks = 0
+            self._refill(iters)
+            while iters or any(len(q) for q in self._queues.values()):
+                ready = self.tick_pop()
+                inflight = self._dispatch(ready) if ready else None
+                self._refill(iters)  # overlaps the dispatched compute
+                if inflight is not None:
+                    self._finish(*inflight)
+                else:
+                    self._finish({}, {})
+                ticks += 1
+                if max_ticks is not None and ticks >= max_ticks:
+                    break
+            return ticks
 
     def _refill(self, iters: Dict[Hashable, Any]) -> None:
         for sid in list(iters):

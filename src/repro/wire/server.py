@@ -20,8 +20,27 @@ Transport layering (relay → queue → pipeline):
   the load generator drive it; zero sockets, same code path);
 * :meth:`IngestServer.serve_tcp` / :meth:`serve_unix` are thin asyncio
   receivers that run the same core on each framed message, one reply
-  per message, in the event-loop thread.  ``handle_message`` holds the
-  server's lock, so a bench thread may call :meth:`tick` concurrently.
+  per message, in the event-loop thread.  A serving thread may call
+  :meth:`tick` concurrently.
+
+**Locks.**  The ingest server adds none: :attr:`IngestServer.lock` is
+the stream server's ``queue_lock``, and the order of that and its
+``pool_lock`` is the stream server's (:mod:`repro.serve.server`).
+
+* A data frame's CRC and parse run before any lock.  The frame then
+  holds the queue lock for its bookkeeping only: the wire seq cursors,
+  credits and resume cursors, the queue push, and the ``wire_*``
+  counters (the metrics registry has no lock of its own).  It never
+  waits for a tick's stack, device step or readback, which run under
+  the pool lock alone; ``wire_frames_during_step_total`` counts the
+  frames acknowledged while a step was in flight.
+* A control frame (OPEN, CLOSE with its drain ticks, RESUME, CREDIT,
+  STATUS) holds both locks (``StreamServer.locked``), so it runs
+  between ticks and sees no half-done step.
+
+A session the stream server evicts on its own (idle or LRU policy) is
+forgotten by the next frame that names it, which NACKs
+``unknown_stream``, or by the next :meth:`IngestServer.tick`.
 
 **Reconnect/resume**: a ``RESUME`` control frame re-binds a dropped
 connection to its live (or just-restored, see
@@ -116,6 +135,7 @@ class IngestServer:
     n_dup_suppressed = counter_property("wire_dup_suppressed_total")
     n_credit_requests = counter_property("wire_credit_requests_total")
     n_credit_granted = counter_property("wire_credit_granted_total")
+    n_frames_during_step = counter_property("wire_frames_during_step_total")
 
     def __init__(
         self,
@@ -127,7 +147,8 @@ class IngestServer:
         self.srv = stream_server
         self.verify_crc = verify_crc
         self.strict_seq = strict_seq
-        self.lock = threading.Lock()
+        # The stream server's queue lock (module docstring).
+        self.lock = stream_server.queue_lock
         # One registry per serving process: adopt the StreamServer's
         # (PR 10) so `wire_*` and `serve_*` families snapshot/export
         # together; fall back to a private one for bare frontiers.
@@ -138,7 +159,7 @@ class IngestServer:
         for _attr in (
             "n_messages", "n_frames_in", "n_opened", "n_closed",
             "n_resumed", "n_dup_suppressed", "n_credit_requests",
-            "n_credit_granted",
+            "n_credit_granted", "n_frames_during_step",
         ):
             getattr(self, _attr)  # materialize zero-valued cells
         self._seq_seen: Dict[int, int] = {}
@@ -211,39 +232,50 @@ class IngestServer:
     def handle_message(self, msg) -> bytes:
         """Process one unframed message; returns the encoded reply.
 
-        With a flight recorder attached to the stream server, a data
-        frame records ``wire.lock_wait`` (entry to holding the lock) and
-        ``wire.decode`` (the CRC and the parse) under its ``(stream,
+        The CRC and the parse run before any lock.  A data frame then
+        holds the queue lock only, so it is acknowledged and queued
+        while a tick's step is in flight; a control frame holds both of
+        the stream server's locks.  With a flight recorder attached to
+        the stream server, a data frame records ``wire.decode`` (the
+        CRC and the parse) and ``wire.lock_wait`` (the end of the
+        decode to holding the queue lock) under its ``(stream,
         seq)``."""
         rec = getattr(self.srv, "recorder", None)
-        if rec is None:
-            with self.lock:
-                return self._handle_locked(msg)
-        t0 = rec.now()
-        with self.lock:
-            return self._handle_locked(msg, rec, t0, rec.now())
-
-    def _handle_locked(self, msg, rec=None, wait0=0.0, wait1=0.0) -> bytes:
-        self.n_messages += 1
         t0 = 0.0 if rec is None else rec.now()
         try:
             kind, frame = codec.decode_message(
                 msg, verify_crc=self.verify_crc
             )
         except codec.WireFormatError:
-            return self._nack(codec.NACK_BAD_FRAME, 0)
-        if rec is not None and kind == "data":
-            rec.chunk_spans(
-                frame.stream_id, frame.seq,
-                ("wire.lock_wait", wait0, wait1),
-                ("wire.decode", t0, rec.now()),
-            )
+            kind, frame = None, None
         if kind == "control":
-            return self._handle_control(frame)
-        if kind != "data":
-            return self._nack(codec.NACK_BAD_FRAME, 0)
+            with self.srv.locked():
+                self.n_messages += 1
+                return self._handle_control(frame)
+        t1 = 0.0 if rec is None else rec.now()
+        with self.lock:
+            self.n_messages += 1
+            if kind != "data":
+                return self._nack(codec.NACK_BAD_FRAME, 0)
+            if rec is not None:
+                rec.chunk_spans(
+                    frame.stream_id, frame.seq,
+                    ("wire.decode", t0, t1),
+                    ("wire.lock_wait", t1, rec.now()),
+                )
+            return self._handle_data(frame)
+
+    def _open(self, sid: int) -> bool:
+        """Whether ``sid`` is an open wire session; one the stream
+        server evicted on its own is forgotten here.  Called under the
+        queue lock, which every eviction holds."""
+        if sid in self._seq_seen and sid not in self.srv._queues:
+            self.session_evicted(sid)
+        return sid in self._seq_seen
+
+    def _handle_data(self, frame: codec.WireFrame) -> bytes:
         sid = frame.stream_id
-        if sid not in self._seq_seen:
+        if not self._open(sid):
             return self._nack(codec.NACK_UNKNOWN_STREAM, sid, frame.seq)
         last = self._seq_seen[sid]
         if last >= 0 and frame.seq <= last:
@@ -271,9 +303,9 @@ class IngestServer:
             return self._nack(codec.NACK_SEQ_GAP, sid, last + 1)
         try:
             ok = self.srv.submit(sid, frame.chunk, seq=frame.seq)
-        except (ValueError, KeyError):
-            # Wrong serving quantum / raced an eviction: the frame is
-            # structurally valid wire but unserveable as submitted.
+        except ValueError:
+            # Wrong serving quantum: the frame is structurally valid
+            # wire but unserveable as submitted.
             return self._nack(codec.NACK_BAD_FRAME, sid, frame.seq)
         if not ok:
             return self._nack(codec.NACK_BACKPRESSURE, sid, frame.seq)
@@ -284,6 +316,8 @@ class IngestServer:
             self._count_gap(sid, gap)
         self._seq_seen[sid] = frame.seq
         self.n_frames_in += 1
+        if self.srv.stepping:
+            self.n_frames_during_step += 1
         out = self._credit.get(sid)
         if out:  # each accepted frame consumes one outstanding credit
             self._credit[sid] = out - 1
@@ -295,7 +329,7 @@ class IngestServer:
     def _handle_control(self, ctl: codec.ControlFrame) -> bytes:
         sid = ctl.stream_id
         if ctl.op == codec.OP_OPEN:
-            if sid in self._seq_seen:
+            if self._open(sid):
                 return self._nack(codec.NACK_DUP_STREAM, sid)
             try:
                 self.srv.admit(sid)
@@ -307,7 +341,7 @@ class IngestServer:
             self.n_opened += 1
             return codec.encode_reply(codec.ACK, sid)
         if ctl.op == codec.OP_RESUME:
-            if sid in self._seq_seen:
+            if self._open(sid):
                 cursor = self._seq_seen[sid]
             elif sid in set(self.srv.live_sessions):
                 # The serving slot is live but this ingest frontier has
@@ -328,7 +362,7 @@ class IngestServer:
             # client replays its unacked window from there.
             return codec.encode_reply(codec.ACK, sid, cursor + 1)
         if ctl.op == codec.OP_CREDIT:
-            if sid not in self._seq_seen:
+            if not self._open(sid):
                 return self._nack(codec.NACK_UNKNOWN_STREAM, sid)
             self.n_credit_requests += 1
             q = self.srv._queues.get(sid)
@@ -343,13 +377,14 @@ class IngestServer:
             return codec.encode_reply(codec.ACK, sid, grant)
         if ctl.op == codec.OP_STATUS:
             # Introspection: answered with an EPWS status reply, not an
-            # EPWR ack.  The caller holds the ingest lock, so the
-            # snapshot is consistent w.r.t. concurrent submits/ticks.
+            # EPWR ack.  The caller holds both of the stream server's
+            # locks, so the snapshot is consistent w.r.t. concurrent
+            # submits and ticks.
             from repro.obs.status import collect_status
 
             return codec.encode_status_reply(collect_status(self))
         # OP_CLOSE (decode_control rejects anything else)
-        if sid not in self._seq_seen:
+        if not self._open(sid):
             return self._nack(codec.NACK_UNKNOWN_STREAM, sid)
         # Drain-then-evict: pending queued chunks are served before the
         # slot frees (matches a producer's "flush and hang up").
@@ -370,22 +405,17 @@ class IngestServer:
         self._credit.pop(stream_id, None)
 
     def tick(self):
-        """Run one serving tick under the ingest lock (safe alongside
-        socket receivers); prunes wire sessions the tick evicted.  With
-        a recorder attached, the wait for the lock is the tick's
-        ``lock_wait`` span."""
+        """Run one serving tick alongside socket receivers, then forget
+        the wire sessions it evicted.  Data frames keep arriving while
+        its step runs (:meth:`StreamServer.tick`).  With a recorder
+        attached, the wait from entry to holding the stream server's
+        locks is the tick's ``lock_wait`` span."""
         rec = getattr(self.srv, "recorder", None)
-        t0 = 0.0 if rec is None else rec.now()
+        stepped = self.srv.tick(wait_since=None if rec is None else rec.now())
         with self.lock:
-            if rec is not None:
-                rec.carry_span("lock_wait", t0, rec.now())
-            stepped = self.srv.tick()
-            live = set(self.srv.live_sessions)
-            for sid in [s for s in self._seq_seen if s not in live]:
-                del self._seq_seen[sid]
-                self._resume_cursor.pop(sid, None)
-                self._credit.pop(sid, None)
-            return stepped
+            for sid in list(self._seq_seen):
+                self._open(sid)
+        return stepped
 
     def counters(self) -> Dict[str, int]:
         return {
@@ -397,6 +427,7 @@ class IngestServer:
             "n_dup_suppressed": self.n_dup_suppressed,
             "n_credit_requests": self.n_credit_requests,
             "n_credit_granted": self.n_credit_granted,
+            "n_frames_during_step": self.n_frames_during_step,
             "credit_outstanding": sum(self._credit.values()),
             "n_out_of_order": self.nacks.get("out_of_order", 0),
             "n_seq_gaps": sum(self.seq_gaps_by_stream.values()),
@@ -415,9 +446,9 @@ class IngestServer:
                     break
                 (nbytes,) = LENGTH_PREFIX.unpack(head)
                 if nbytes > MAX_MESSAGE_NBYTES:
-                    writer.write(
-                        frame_message(self._nack(codec.NACK_BAD_FRAME, 0))
-                    )
+                    with self.lock:
+                        nack = self._nack(codec.NACK_BAD_FRAME, 0)
+                    writer.write(frame_message(nack))
                     break
                 msg = await reader.readexactly(nbytes)
                 writer.write(frame_message(self.handle_message(msg)))

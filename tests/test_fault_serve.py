@@ -295,6 +295,34 @@ class TestSnapshotRestore:
         )))
         assert reply.status == codec.NACK_OUT_OF_ORDER
 
+    def test_frames_during_step_counter_roundtrips(
+        self, tmp_path, monkeypatch
+    ):
+        from repro.serve import checkpoint
+
+        srv = StreamServer(_comp(0), _server_cfg(k_ladder=None))
+        ingest = IngestServer(srv)
+        ingest.n_frames_in = 9
+        ingest.n_frames_during_step = 7
+        save_server(str(tmp_path / "new"), 1, srv, ingest=ingest)
+        _, ing2, _ = restore_server(
+            str(tmp_path / "new"), _comp(0), with_ingest=True
+        )
+        assert ing2.counters()["n_frames_during_step"] == 7
+        # a checkpoint written before the counter existed restores it 0
+        monkeypatch.setattr(
+            checkpoint, "_WIRE_COUNTER_ATTRS",
+            tuple(a for a in checkpoint._WIRE_COUNTER_ATTRS
+                  if a != "n_frames_during_step"),
+        )
+        save_server(str(tmp_path / "old"), 1, srv, ingest=ingest)
+        monkeypatch.undo()
+        _, ing3, _ = restore_server(
+            str(tmp_path / "old"), _comp(0), with_ingest=True
+        )
+        assert ing3.counters()["n_frames_in"] == 9
+        assert ing3.counters()["n_frames_during_step"] == 0
+
 
 # ---------------------------------------------------------------------------
 # Checkpointer cadence

@@ -573,11 +573,12 @@ class TestWireTracing:
         chunks = rec.chunks()
         assert [(c["stream"], c["seq"]) for c in chunks] == sent
         for c in chunks:
+            # the decode runs before the ingest lock is taken
             assert [n for n, _, _ in c["spans"]] == [
-                "wire.lock_wait", "wire.decode", "queue.wait",
+                "wire.decode", "wire.lock_wait", "queue.wait",
             ]
-            (_, w0, w1), (_, d0, d1), (_, q0, q1) = c["spans"]
-            assert w0 <= w1 <= d0 <= d1 <= q0 <= q1
+            (_, d0, d1), (_, w0, w1), (_, q0, q1) = c["spans"]
+            assert d0 <= d1 <= w0 <= w1 <= q0 <= q1
         ticks = rec.ticks()
         assert [c["tick"] for c in chunks] == [0, 0, 1, 1]
         for tk in ticks:

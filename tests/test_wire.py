@@ -7,6 +7,8 @@ semantics, latency histogram math, and the TCP socket path."""
 
 import math
 import os
+import threading
+import time
 import zlib
 
 import jax
@@ -1380,3 +1382,291 @@ class TestWireClientTimeout:
                 conn.close()
             srv_sock.close()
             t.join(timeout=2)
+
+
+# ---------------------------------------------------------------------------
+# Lock scope: data frames during a tick's step; control frames after it
+
+
+class _Call:
+    """Run ``fn`` on a daemon thread; ``done`` is set when it returns."""
+
+    def __init__(self, fn):
+        self.done = threading.Event()
+        self.result = None
+        self.error = None
+
+        def run():
+            try:
+                self.result = fn()
+            except BaseException as e:  # re-raised by join()
+                self.error = e
+            finally:
+                self.done.set()
+
+        self.thread = threading.Thread(target=run, daemon=True)
+        self.thread.start()
+
+    def join(self, timeout=60.0):
+        self.thread.join(timeout)
+        assert not self.thread.is_alive(), "the call did not return"
+        if self.error is not None:
+            raise self.error
+        return self.result
+
+
+def _hold_step(srv):
+    """Make the next ``tick_step`` wait for ``release``; ``entered`` is
+    set once a tick is inside its step phase."""
+    entered, release = threading.Event(), threading.Event()
+    orig = srv.tick_step
+
+    def held(ready):
+        entered.set()
+        assert release.wait(timeout=60)
+        return orig(ready)
+
+    srv.tick_step = held
+    return entered, release
+
+
+class TestLockScope:
+    def _wire_server(self, capacity=4, k_ladder=None, queue_depth=2, **kw):
+        srv = StreamServer(
+            api.EPICCompressor(_ecfg(prefilter_k=8 if k_ladder else 0)),
+            ServerConfig(
+                capacity=capacity, chunk_frames=CHUNK,
+                queue_depth=queue_depth, k_ladder=k_ladder, **kw,
+            ),
+        )
+        ingest = IngestServer(srv)
+        return srv, ingest, Loopback(ingest)
+
+    def _warm(self, ingest, loop, sid, chunk):
+        """Open ``sid`` and serve one chunk (compiles the step)."""
+        assert loop.send(codec.encode_control(codec.OP_OPEN, sid)).ok
+        assert loop.send(codec.encode_chunk(
+            chunk, stream_id=sid, seq=0, timestamp_ns=0
+        )).ok
+        assert ingest.tick() == [sid]
+
+    def test_data_frame_acked_and_queued_during_step(self):
+        srv, ingest, loop = self._wire_server()
+        chunks = _sensor_chunks(0, n_frames=24)
+        self._warm(ingest, loop, 1, chunks[0])
+        assert loop.send(codec.encode_chunk(
+            chunks[1], stream_id=1, seq=1, timestamp_ns=1
+        )).ok
+        entered, release = _hold_step(srv)
+        try:
+            tick = _Call(ingest.tick)
+            assert entered.wait(timeout=30)
+            # the tick popped seq 1 and is stepping it: seq 2 goes in
+            send = _Call(lambda: loop.send(codec.encode_chunk(
+                chunks[2], stream_id=1, seq=2, timestamp_ns=2
+            )))
+            assert send.done.wait(timeout=30), "the ACK waited for the step"
+            assert send.join().ok
+            assert not tick.done.is_set()
+            assert len(srv._queues[1]) == 1
+            assert ingest.counters()["n_frames_during_step"] == 1
+        finally:
+            release.set()
+        assert tick.join() == [1]
+        assert ingest.tick() == [1]  # the queued frame serves next
+        assert srv.telemetry(1).n_chunks == 3
+        assert srv.metrics.value("wire_frames_during_step_total") == 1
+        assert loop.status()["wire_counters"]["n_frames_during_step"] == 1
+
+    def test_control_frames_and_snapshot_wait_for_the_step(self):
+        from repro.serve.checkpoint import snapshot_server
+
+        srv, ingest, loop = self._wire_server()
+        chunks = _sensor_chunks(0, n_frames=16)
+        self._warm(ingest, loop, 1, chunks[0])
+        assert loop.send(codec.encode_control(codec.OP_OPEN, 3)).ok
+        assert loop.send(codec.encode_chunk(
+            chunks[1], stream_id=1, seq=1, timestamp_ns=1
+        )).ok
+        ticks_before = srv.n_ticks
+        entered, release = _hold_step(srv)
+        try:
+            tick = _Call(ingest.tick)
+            assert entered.wait(timeout=30)
+            calls = {
+                "open": _Call(lambda: loop.send(
+                    codec.encode_control(codec.OP_OPEN, 2)
+                )),
+                "close": _Call(lambda: loop.send(
+                    codec.encode_control(codec.OP_CLOSE, 3)
+                )),
+                "status": _Call(loop.status),
+                "snapshot": _Call(
+                    lambda: snapshot_server(srv, ingest=ingest)
+                ),
+            }
+            for name, call in calls.items():
+                assert not call.done.wait(timeout=0.2), (
+                    f"{name} ran during the step"
+                )
+            assert srv.live_sessions == [1, 3]
+        finally:
+            release.set()
+        assert tick.join() == [1]
+        assert calls["open"].join().ok
+        assert calls["close"].join().ok
+        status = calls["status"].join()
+        _, meta = calls["snapshot"].join()
+        # each saw the tick's step completed
+        assert status["tick"] == ticks_before + 1
+        assert meta["counters"]["n_ticks"] == ticks_before + 1
+        assert sorted(srv.live_sessions) == [1, 2]
+
+    def test_threaded_sender_and_ticker_match_sequential_serving(self):
+        ladder = (8, 16, 32)
+        sids = (1, 2, 3, 4)
+        feeds = {
+            sid: _sensor_chunks(sid, n_frames=32, n_obj=3 + sid % 3)
+            for sid in sids
+        }
+        srv, ingest, loop = self._wire_server(k_ladder=ladder)
+        for sid in sids:
+            assert loop.send(codec.encode_control(codec.OP_OPEN, sid)).ok
+        sent = threading.Event()
+        deadline = time.monotonic() + 240
+
+        def sender():
+            nxt = {sid: 0 for sid in sids}
+            while any(nxt[s] < len(feeds[s]) for s in sids):
+                assert time.monotonic() < deadline, "sender timed out"
+                progress = False
+                for sid in sids:
+                    seq = nxt[sid]
+                    if seq == len(feeds[sid]):
+                        continue
+                    r = loop.send(codec.encode_chunk(
+                        feeds[sid][seq], stream_id=sid, seq=seq,
+                        timestamp_ns=seq,
+                    ))
+                    if r.ok:
+                        nxt[sid] += 1
+                        progress = True
+                    else:
+                        assert r.status_name == "backpressure", r
+                if not progress:
+                    sent.wait(timeout=0.001)
+            sent.set()
+
+        def ticker():
+            while not (
+                sent.is_set()
+                and not any(len(q) for q in srv._queues.values())
+            ):
+                assert time.monotonic() < deadline, "ticker timed out"
+                ingest.tick()
+
+        calls = [_Call(sender), _Call(ticker)]
+        for call in calls:
+            call.join(timeout=300)
+
+        seq_srv = StreamServer(
+            api.EPICCompressor(_ecfg(prefilter_k=8)),
+            ServerConfig(
+                capacity=4, chunk_frames=CHUNK, queue_depth=2,
+                k_ladder=ladder,
+            ),
+        )
+        for sid in sids:
+            seq_srv.admit(sid)
+        for sid in sids:
+            for c in feeds[sid]:
+                assert seq_srv.submit(sid, c)
+                assert seq_srv.tick() == [sid]
+        for sid in sids:
+            got, want = srv.telemetry(sid), seq_srv.telemetry(sid)
+            for field in ("n_chunks", "n_frames", "n_processed",
+                          "n_inserted", "buffer_valid"):
+                assert getattr(got, field) == getattr(want, field), field
+            assert list(got.k_trajectory) == list(want.k_trajectory)
+            _assert_tree_bitwise(
+                srv.state(sid), seq_srv.state(sid), f"stream {sid}"
+            )
+        assert ingest.counters()["n_frames_in"] == sum(
+            len(f) for f in feeds.values()
+        )
+
+    def test_frames_during_step_stays_zero_single_threaded(self):
+        srv, ingest, loop = self._wire_server()
+        chunks = _sensor_chunks(0, n_frames=24)
+        assert loop.send(codec.encode_control(codec.OP_OPEN, 1)).ok
+        for seq, c in enumerate(chunks):
+            assert loop.send(codec.encode_chunk(
+                c, stream_id=1, seq=seq, timestamp_ns=seq
+            )).ok
+            ingest.tick()
+        c = ingest.counters()
+        assert (c["n_frames_in"], c["n_frames_during_step"]) == (3, 0)
+        assert loop.status()["wire_counters"]["n_frames_during_step"] == 0
+
+    def test_idle_eviction_keeps_a_frame_acked_during_the_step(self):
+        # idle_frames=CHUNK: a stream that does not step in a tick is
+        # idle at its end.  Stream 2 steps nothing in the held tick, but
+        # a frame of it is ACKed during the step: it must be served,
+        # not dropped with an evicted stream.
+        srv, ingest, loop = self._wire_server(
+            eviction="idle", idle_frames=CHUNK
+        )
+        chunks = _sensor_chunks(0, n_frames=24)
+        for sid in (1, 2):
+            assert loop.send(codec.encode_control(codec.OP_OPEN, sid)).ok
+            assert loop.send(codec.encode_chunk(
+                chunks[0], stream_id=sid, seq=0, timestamp_ns=0
+            )).ok
+        assert sorted(ingest.tick()) == [1, 2]
+        assert loop.send(codec.encode_chunk(
+            chunks[1], stream_id=1, seq=1, timestamp_ns=1
+        )).ok
+        entered, release = _hold_step(srv)
+        try:
+            tick = _Call(ingest.tick)
+            assert entered.wait(timeout=30)
+            send = _Call(lambda: loop.send(codec.encode_chunk(
+                chunks[1], stream_id=2, seq=1, timestamp_ns=1
+            )))
+            reply = send.join(timeout=30)
+        finally:
+            release.set()
+        assert tick.join() == [1]
+        assert reply.ok
+        assert 2 in srv.live_sessions and len(srv._queues[2]) == 1
+        assert ingest.tick() == [2]  # served; stream 1 idles out
+        assert srv.live_sessions == [2]
+        assert srv.telemetry(2).n_chunks == 2
+        assert srv.server_counters()["n_dropped"] == 0
+
+    def test_frames_for_a_stream_evicted_before_the_prune(self):
+        # The stream server evicts on its own (a bare tick, so the wire
+        # frontier has not pruned yet): every frame naming the stream
+        # NACKs unknown_stream, and the session is forgotten.
+        srv, ingest, loop = self._wire_server(
+            eviction="idle", idle_frames=CHUNK
+        )
+        chunk = _sensor_chunks(0)[0]
+        for sid in (1, 2, 3):
+            assert loop.send(codec.encode_control(codec.OP_OPEN, sid)).ok
+        srv.tick()
+        assert srv.live_sessions == [] and sorted(ingest._seq_seen) == [
+            1, 2, 3,
+        ]
+        r = loop.send(codec.encode_chunk(
+            chunk, stream_id=1, seq=0, timestamp_ns=0
+        ))
+        assert r.status_name == "unknown_stream"
+        for msg in (
+            codec.encode_control(codec.OP_CLOSE, 2),
+            codec.encode_credit(3, 1),
+        ):
+            assert loop.send(msg).status_name == "unknown_stream"
+        assert ingest._seq_seen == {}
+        # the stream may be opened again
+        assert loop.send(codec.encode_control(codec.OP_OPEN, 1)).ok
