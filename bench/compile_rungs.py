@@ -4,7 +4,7 @@ Usage, from the root of a checkout (``JAX_PLATFORMS=cpu`` is fine):
 
     python3 bench/compile_rungs.py --config glasses1m --streams 8
 
-Builds the server a cell would build (``bench/harness.py``), lowers each
+Builds the server a cell would build (``bench/paths/compress.py``), lowers each
 rung's full-capacity masked slot-pool step for one chip of a described
 ``v5e:2x2`` topology, compiles it, and prints the compiler's memory
 analysis (argument and temporary bytes) and whether the step carries the
@@ -35,12 +35,13 @@ def main() -> int:
     from jax.sharding import SingleDeviceSharding
 
     from bench import harness
+    from bench.paths import compress
     from repro.api import SensorChunk
 
     cfg = harness.config(harness.spec(), args.config)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    srv, _ = harness.build_server(cfg, args.streams)
+    srv, _ = compress.build_server(cfg, args.streams)
     f, cf, s = cfg["frame"], cfg["chunk_frames"], args.streams
 
     def on_chip(tree):

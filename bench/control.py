@@ -13,7 +13,7 @@ in bfloat16 (the precision below the configuration's).  It prints the
 numbers a run compares (the share of retained entries that differ,
 exact counters that differ, the gap in inserted patches per million) for the bfloat16 session
 against the float32 one: the upper readings from which the limits of
-``harness.LIMITS`` are set.
+``LIMITS`` (``bench/paths/compress.py``) are set.
 """
 
 from __future__ import annotations
@@ -38,6 +38,7 @@ def main() -> int:
     import numpy as np
 
     from bench import harness, reference
+    from bench.paths import compress
 
     bench = harness.spec()
     cell = harness.workload(bench, args.workload)
@@ -46,14 +47,14 @@ def main() -> int:
     p = reference.Params.of(cfg)
     for seed in args.seeds:
         t = time.monotonic()
-        bank = harness.make_bank(seed, cfg, mix)
+        bank = compress.make_bank(seed, cfg, mix)
         rng = np.random.default_rng(seed)
         sids = [0] + sorted(
             1 + rng.choice(mix["streams"] - 1, size=mix["sample_streams"] - 1, replace=False)
         )
         total = {"entries_off": 0, "entries": 0, "counters_off": 0, "inserted_gap": 0, "inserted": 0}
         for sid in sids:
-            chunks = list(harness.stream_chunks(bank, sid, list(range(args.chunks)), mix["streams"]))
+            chunks = list(compress.stream_chunks(bank, sid, list(range(args.chunks)), mix["streams"]))
             want = reference.run_stream(p, cfg["k_ladder"], chunks)
             got = reference.run_stream(p, cfg["k_ladder"], chunks, dtype=jnp.bfloat16)
             d = reference.compare(got, want)

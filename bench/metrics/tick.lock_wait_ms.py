@@ -1,6 +1,8 @@
 """Mean ``lock_wait`` span per stepped tick (``FlightRecorder``):
-``IngestServer.tick`` waiting for the ingest lock, which a data frame's
-decode holds; over the ticks of the window before the traced slice."""
+``IngestServer.tick`` from entry to holding ``StreamServer.pool_lock``
+and ``queue_lock``, before its pops.  No decode runs under either lock,
+so it waits for another thread's bookkeeping or a control frame; over
+the ticks of the window before the traced slice."""
 
 from bench import spans
 

@@ -1,7 +1,8 @@
 """Mean ``wire.lock_wait`` span per data frame (``FlightRecorder`` chunk
-records): ``IngestServer.handle_message`` from entry to holding the ingest
-lock, which the tick holds while it runs; over the chunks popped by the
-ticks of the window before the traced slice."""
+records): ``IngestServer.handle_message`` from the end of the frame's
+decode to holding ``StreamServer.queue_lock`` (the ingest lock), which a
+tick holds for its pops and idle evictions, not for its step; over the
+chunks popped by the ticks of the window before the traced slice."""
 
 from bench import spans
 

@@ -12,9 +12,9 @@ The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 window by the readers under ``bench/metrics/``.  The last line of
 standard output is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, with ``--trace 1`` ``breakdown``,
-and last ``checks`` (each number compared with the reference, beside its
-limit).  Without a TPU, or with fewer chips than the cell asks for, it
-prints no result and exits 2; any other failure exits 1.
+and last ``checks`` (each number the configuration's path compares,
+beside its limit).  Without a TPU, or with fewer chips than the cell
+asks for, it prints no result and exits 2; any other failure exits 1.
 """
 
 from __future__ import annotations

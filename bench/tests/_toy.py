@@ -27,8 +27,11 @@ def traffic(loop="closed"):
     return mix
 
 
-def run(harness, tmp_path, before_window=None, loop="closed", seconds=0.6, seed=2**33 + 7):
+def run(harness, tmp_path, before_window=None, loop="closed", seconds=0.6, seed=2**33 + 7,
+        path=None):
     cfg = config()
+    if path is not None:
+        cfg["path"] = path
     if loop == "open":
         cfg["fps"] = 40
     e2e = [

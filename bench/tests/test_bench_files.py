@@ -10,6 +10,7 @@ import sys
 import pytest
 
 from bench import harness
+from bench.paths import compress
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 SPEC = harness.spec()
@@ -38,7 +39,7 @@ def test_cell_resolves(cell):
     assert mix["loop"] in ("closed", "open")
     assert cfg["frame"] % cfg["patch"] == 0
     assert mix["clip_frames"] % cfg["chunk_frames"] == 0
-    assert harness.bank_clips(cfg, mix) >= 1
+    assert compress.bank_clips(cfg, mix) >= 1
     e2e, layer = harness.metrics_of(SPEC, cell)
     names = {m["name"] for m in e2e}
     assert "setup_s" in names and len(names) >= 2
@@ -55,6 +56,13 @@ def test_configs_name_their_files():
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
         assert cfg["reduced"] == c["reduced"]
         assert "assumed" in cfg
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in SPEC["configs"]])
+def test_config_path_resolves(name):
+    cfg = harness.config(SPEC, name)
+    path = harness.path_module(cfg.get("path", harness.DEFAULT_PATH))
+    assert callable(path.Path) and path.LIMITS
 
 
 def test_peaks_name_the_chip():

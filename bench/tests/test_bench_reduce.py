@@ -6,7 +6,8 @@ import math
 import pytest
 
 from bench import kernels, stats, tracing
-from bench.harness import LayerInput, Stepped, reader
+from bench.harness import LayerInput, reader
+from bench.paths.compress import Stepped
 
 TRACE = """
 planes {

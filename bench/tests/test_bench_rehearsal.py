@@ -6,13 +6,14 @@ import jax.numpy as jnp
 import pytest
 
 from bench import harness, reference
+from bench.paths import compress
 from bench.tests import _toy
 
 
 def test_sound_run_is_correct(tmp_path):
     out = _toy.run(harness, tmp_path)
     assert out.correct, out.checks
-    assert set(out.checks) == set(harness.LIMITS)
+    assert set(out.checks) == set(compress.LIMITS)
     assert out.checks["unserved_chunks"] == {"value": 0, "limit": 0}
     assert out.checks["counters_off"] == {"value": 0, "limit": 0}
     assert out.metrics["frames_per_s"]["value"] > 0
@@ -81,22 +82,22 @@ def test_broken_path_is_not_correct(tmp_path, fault):
 def test_control_lower_precision_fails(tmp_path):
     """The reference in bfloat16, put in the program's place, differs."""
     cfg, mix = _toy.config(), _toy.traffic()
-    bank = harness.make_bank(11, cfg, mix)
-    chunks = list(harness.stream_chunks(bank, 0, list(range(6)), mix["streams"]))
+    bank = compress.make_bank(11, cfg, mix)
+    chunks = list(compress.stream_chunks(bank, 0, list(range(6)), mix["streams"]))
     p = reference.Params.of(cfg)
     want = reference.run_stream(p, cfg["k_ladder"], chunks)
     got = reference.run_stream(p, cfg["k_ladder"], chunks, dtype=jnp.bfloat16)
     d = reference.compare(got, want)
     share = 100.0 * d.entries / want.retained["valid"].shape[0]
-    assert share > harness.LIMITS["entries_off_pct"]
+    assert share > compress.LIMITS["entries_off_pct"]
     assert reference.compare(want, want) == reference.Diff(0, 0, 0, [])
 
 
 def test_counters_are_held_exactly():
     """A served session whose counters alone are wrong differs."""
     cfg, mix = _toy.config(), _toy.traffic()
-    bank = harness.make_bank(12, cfg, mix)
-    chunks = list(harness.stream_chunks(bank, 1, list(range(4)), mix["streams"]))
+    bank = compress.make_bank(12, cfg, mix)
+    chunks = list(compress.stream_chunks(bank, 1, list(range(4)), mix["streams"]))
     want = reference.run_stream(reference.Params.of(cfg), cfg["k_ladder"], chunks)
     k = list(want.counters["k_trajectory"])
     bad = [

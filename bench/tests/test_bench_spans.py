@@ -6,7 +6,8 @@ it."""
 import pytest
 
 from bench import scopes, tracing
-from bench.harness import LayerInput, Stepped, reader
+from bench.harness import LayerInput, reader
+from bench.paths.compress import Stepped
 
 # Ops at 1-3 ms (under ``tsrc``), 2-4 ms (under ``tsrc``, overlapping)
 # and 6-7 ms (under ``bypass``); one op at 8-9 ms carries no path.
